@@ -38,6 +38,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> int:
+    from repro.sim.compile_cache import use_compile_cache
+
+    use_compile_cache()
     fast = os.environ.get("FAST", "0") == "1"
     t0 = time.time()
     collected: List[Dict] = []

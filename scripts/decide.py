@@ -33,6 +33,7 @@ from repro.kernels.registry import TICK_IMPL_CHOICES
 from repro.obs.logs import LOG_LEVELS, setup_logging
 from repro.obs.metrics import get_registry
 from repro.obs.trace import get_tracer
+from repro.sim.compile_cache import use_compile_cache
 from repro.sim.decide import OnPremDisk, decide
 from repro.sim.jobs import RetryPolicy
 from repro.sim.sweep import SweepDriver, run_sweep
@@ -148,7 +149,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tick-impl", default="auto",
                     choices=TICK_IMPL_CHOICES,
                     help="jax-backend kernel implementation (auto = "
-                         "compiled Pallas on an accelerator, jnp on CPU; "
+                         "jnp on every platform; "
                          "see docs/simulation.md, 'Kernel selection')")
     ap.add_argument("--lane-chunk", type=int, default=None)
     ap.add_argument("--workers", type=int, default=None)
@@ -218,6 +219,8 @@ def main(argv=None) -> int:
         retry = RetryPolicy(max_attempts=args.retries)
     elif args.resume:
         retry = RetryPolicy()  # engage the jobs layer so completions journal
+    if args.backend == "jax" or args.cross_check:
+        use_compile_cache()
     try:
         driver = SweepDriver(backend=args.backend, tick=args.tick,
                              workers=args.workers, tick_impl=args.tick_impl,

@@ -46,6 +46,7 @@ from repro.kernels.registry import TICK_IMPL_CHOICES
 from repro.obs.logs import LOG_LEVELS, setup_logging
 from repro.obs.metrics import get_registry
 from repro.obs.trace import get_tracer, jax_device_profile
+from repro.sim.compile_cache import use_compile_cache
 from repro.sim.jobs import RetryPolicy
 from repro.sim.output import write_csv
 from repro.sim.sweep import run_sweep
@@ -154,7 +155,7 @@ def main(argv=None) -> int:
                          "accelerator), pallas_interpret (kernels traced "
                          "through the Pallas interpreter — parity/CI "
                          "path, not a speed mode), or auto (default: "
-                         "pallas on an accelerator, jnp on CPU). See "
+                         "jnp on every platform). See "
                          "docs/simulation.md, 'Kernel selection'")
     ap.add_argument("--lane-chunk", type=int, default=None, metavar="N",
                     help="jax backend: simulate at most N dynamics lanes "
@@ -276,6 +277,7 @@ def main(argv=None) -> int:
         log.error("--shard requires --backend jax")
         return 2
     if args.backend == "jax":
+        use_compile_cache()
         chunk = ("" if args.lane_chunk is None
                  else f", lane_chunk={args.lane_chunk}")
         log.info("sweep: %d configs, backend=jax (tick=%gs, tick_impl=%s%s)",

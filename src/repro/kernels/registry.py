@@ -17,13 +17,12 @@ previously leaked through ``simulate_packed``/``run_sweep_jax``/
 - ``"pallas_interpret"``: the same kernels in Pallas interpret mode —
   traced to regular XLA ops, so they run (slowly) on any backend. This
   is the CI-runnable parity path, not a performance mode.
-- ``"auto"``: resolve per host — ``"pallas"`` when
-  ``jax.default_backend()`` is an accelerator, else ``"jnp"``. ``auto``
-  never silently selects interpret mode: pinning ``JAX_PLATFORMS=cpu``
-  on an accelerator host makes ``jax.default_backend()`` report ``cpu``
-  and resolution lands on ``"jnp"``, and an unpinned accelerator host
-  gets the compiled kernel or a loud compile error — never a 100x-slow
-  interpret run.
+- ``"auto"``: the jnp program, on every platform. Compiled Pallas is
+  refused by Mosaic for the paper's two-site shapes (its ``(1, F)`` row
+  blocks over ``[S, F]`` break the (8, 128) tiling rule), and no chip
+  measurement shows it winning, so ``auto`` never selects it; an
+  explicit ``"pallas"`` still compiles the kernels and fails loudly
+  with Mosaic's error. ``auto`` never selects interpret mode either.
 
 Naming note: ``tick_impl`` selects the *kernel implementation*; the
 neighbouring ``tick=`` float on ``run_sweep``/``SweepDriver``/the CLIs
@@ -31,9 +30,11 @@ is the *clock step duration in seconds*. The two axes are independent
 (``--tick 60 --tick-impl pallas_interpret`` is a coarse-clock interpret
 run).
 
-``jax`` is imported lazily — resolving a concrete name ("jnp",
-"pallas", "pallas_interpret") never touches jax, so jax-free flows
-(the process backend, cache keying of concrete impls) stay jax-free.
+``jax`` is imported lazily — resolving a name never touches jax, so
+jax-free flows (the process backend, cache keying) stay jax-free.
+``on_accelerator`` probes the backend for callers that must know the
+platform (the interpret default of bare kernel calls, and the sweep's
+refusal to send accelerator work to CPU-pinned fleet workers).
 """
 
 from __future__ import annotations
@@ -83,9 +84,9 @@ def on_accelerator() -> bool:
 
 
 def default_tick_impl() -> str:
-    """Resolve ``"auto"`` for this host: compiled Pallas on an
-    accelerator, the jnp program on CPU (never interpret mode)."""
-    return "pallas" if on_accelerator() else "jnp"
+    """Resolve ``"auto"``: the jnp program on every platform, until a
+    chip measurement shows a kernel implementation winning."""
+    return "jnp"
 
 
 def default_interpret() -> bool:
@@ -99,9 +100,8 @@ def default_interpret() -> bool:
 def resolve_tick_impl(name: Optional[str] = "auto") -> TickImpl:
     """Resolve a ``tick_impl`` name to its :class:`TickImpl` record.
 
-    ``"auto"`` (or ``None``) resolves per host via
-    :func:`default_tick_impl`; concrete names resolve without importing
-    jax. Unknown names raise ``ValueError``.
+    ``"auto"`` (or ``None``) resolves via :func:`default_tick_impl`; no
+    name imports jax. Unknown names raise ``ValueError``.
     """
     if name is None:
         name = "auto"
@@ -113,7 +113,7 @@ def resolve_tick_impl(name: Optional[str] = "auto") -> TickImpl:
             "removed use_pallas= flag landing in the tick_impl slot; "
             "use tick_impl="
             f"{'pallas_interpret' if name else 'jnp'!r} "
-            "(or 'pallas'/'auto' to compile on an accelerator)")
+            "(or 'pallas' to compile the kernels on an accelerator)")
     requested = name
     if name == "auto":
         name = default_tick_impl()

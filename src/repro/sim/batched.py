@@ -11,7 +11,7 @@ path) or the fused lane-blocked Pallas kernels
 (``repro.kernels.lane_tick``; ``tick_impl="pallas"`` compiled on an
 accelerator, ``"pallas_interpret"`` as the CI-runnable parity path).
 The implementation axis is the ``tick_impl`` registry
-(``repro.kernels.registry``; ``"auto"`` resolves per host) threaded
+(``repro.kernels.registry``; ``"auto"`` resolves to jnp) threaded
 down from ``run_sweep``/``SweepDriver``. The paper's billing
 quantities — GCS
 byte-seconds, tiered egress volume, class A/B operation counts — are
@@ -206,6 +206,18 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
         t_active = st["tr_slot"] & (st["tr_start"] <= now_prev + 0.5)
         ltype = st["tr_link"] % 3  # 0 tape->disk, 1 gcs->disk, 2 disk->gcs
         loc_onehot = ltype[:, :, None] == jnp.arange(3, dtype=jnp.int32)
+
+        def per_link(table):
+            """``table[tr_link]`` for every file row, as a select over the
+            row's link type instead of a gather. A file uses only its own
+            site's links. In XLA's cost model for a TPU v5e, five such
+            [S, F] gathers were about 94% of the paper-width tick's
+            memory traffic. Rows with no transfer yet hold link 0 and read
+            their own site's type-0 entry; every use masks those rows."""
+            t3 = table.reshape(S, 3)
+            return jnp.where(ltype == 0, t3[:, 0:1],
+                             jnp.where(ltype == 1, t3[:, 1:2], t3[:, 2:3]))
+
         if use_kernel:
             month_onehot = (jnp.arange(n_months, dtype=jnp.int32)
                             == month).astype(jnp.float32)
@@ -218,9 +230,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
             act_f = t_active.astype(jnp.float32)
             counts = jnp.sum(act_f[:, :, None] * loc_onehot,
                              axis=1).reshape(-1)  # [M], M = 3*S
-            bw_i = bw[st["tr_link"]]
-            shared = bw_i / jnp.maximum(counts[st["tr_link"]], 1.0)
-            rate = jnp.where(mode[st["tr_link"]] > 0, bw_i, shared)
+            bw_i = per_link(bw)
+            shared = bw_i / jnp.maximum(per_link(counts), 1.0)
+            rate = jnp.where(per_link(mode) > 0, bw_i, shared)
             new_done = jnp.minimum(st["tr_total"],
                                    st["tr_done"] + act_f * rate * dt)
             comp = (new_done >= st["tr_total"]) & t_active
@@ -278,9 +290,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
         admit = jnp.minimum(free, n_q).astype(jnp.int32)
         new_serve = st["lq_serve"] + admit
         adm_row = st["lq_queued"] & \
-            (st["lq_ticket"] < new_serve[st["tr_link"]])
+            (st["lq_ticket"] < per_link(new_serve))
         st["tr_slot"] = st["tr_slot"] | adm_row
-        st["tr_start"] = jnp.where(adm_row, now + latency[st["tr_link"]],
+        st["tr_start"] = jnp.where(adm_row, now + per_link(latency),
                                    st["tr_start"])
         st["lq_queued"] = st["lq_queued"] & ~adm_row
         st["lq_serve"] = new_serve
@@ -697,6 +709,15 @@ def _build_lane_sim(S: int, K: int, n_months: int, impl_name: str,
                 ser_run=jnp.zeros((n_samples + 1, S), jnp.float32),
                 ser_link=jnp.zeros((n_samples + 1, S, 3), jnp.float32),
             )
+        # Under ``shard_map`` the lane inputs vary over the mesh axis while
+        # these constants do not, and scan requires the carry's varying
+        # axes to match the tick's output. Cast the initial carry to vary
+        # over whatever the lane inputs vary over: a type-level cast with
+        # no effect on the values, and a no-op outside ``shard_map``.
+        vma = tuple(sorted(jax.typeof(sizes).vma))
+        if vma:
+            init = jax.tree.map(
+                lambda x: jax.lax.pcast(x, vma, to="varying"), init)
         final, _ = jax.lax.scan(
             lambda c, xs: tick_fn(c, xs, const), init,
             (times, dts, month_idx, t_idx, jobs_per_tick))
@@ -737,17 +758,15 @@ def _shard_program(S: int, K: int, n_months: int, impl_name: str,
     ``n_shards``; callers pad by replicating the last lane, exactly as
     the chunked path does. The 5 shared tick-grid arguments are
     replicated to every device."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.parallel.sharding import LANES_AXIS, lane_mesh
 
     lane_sim = _build_lane_sim(S, K, n_months, impl_name, record)
     mesh = lane_mesh(n_shards)
     P = jax.sharding.PartitionSpec
     in_specs = (P(),) * 5 + (P(LANES_AXIS),) * 15
-    sharded = shard_map(jax.vmap(lane_sim, in_axes=_LANE_AXES),
-                        mesh=mesh, in_specs=in_specs,
-                        out_specs=P(LANES_AXIS))
+    sharded = jax.shard_map(jax.vmap(lane_sim, in_axes=_LANE_AXES),
+                            mesh=mesh, in_specs=in_specs,
+                            out_specs=P(LANES_AXIS))
     return jax.jit(sharded)
 
 
@@ -768,8 +787,8 @@ def simulate_packed(grid: "PackedGrid", tick_impl: str = "auto",
 
     ``tick_impl`` selects the tick-engine implementation
     (``repro.kernels.registry``): ``"jnp"`` | ``"pallas"`` |
-    ``"pallas_interpret"`` | ``"auto"`` (compiled Pallas on an
-    accelerator, jnp on CPU — never silently interpret mode). The
+    ``"pallas_interpret"`` | ``"auto"`` (the jnp program on every
+    platform — never silently interpret mode). The
     pre-registry ``use_pallas=``/``interpret=`` aliases are gone; a
     boolean landing in the ``tick_impl`` slot raises with the upgrade
     hint (``resolve_tick_impl``).

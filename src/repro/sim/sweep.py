@@ -313,6 +313,22 @@ def _jobs_engaged(backend: str, retry: Any, faults: Any,
             or faults is not None or transport is not None)
 
 
+def _refuse_fleet_on_accelerator(backend: str, transport: Any) -> None:
+    """Raise when jax-backend lanes would go to the worker fleet from an
+    accelerator host. Fleet workers pin ``JAX_PLATFORMS=cpu``, and a chip
+    belongs to one process, so the lanes would run on the host CPU."""
+    if backend != "jax" or transport is None:
+        return
+    from repro.kernels.registry import on_accelerator
+
+    if on_accelerator():
+        raise ValueError(
+            "backend='jax' with transport= on an accelerator host: fleet "
+            "workers run on the CPU, and a chip belongs to one process, so "
+            "the lanes would leave the accelerator; use shard=True to "
+            "spread lanes over this host's devices")
+
+
 def _journal_to_cache(cache: Any, backend: str, tick: float,
                       tick_impl: Optional[str]) -> Callable:
     """A per-job completion hook that checkpoints results into the
@@ -373,8 +389,8 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
     ``tick_impl`` (jax backend only) selects the tick-engine *kernel
     implementation* — ``"jnp"`` | ``"pallas"`` | ``"pallas_interpret"``
     | ``"auto"`` (``repro.kernels.registry``; ``"auto"`` resolves to the
-    compiled Pallas kernels on an accelerator and the jnp program on
-    CPU). Not to be confused with ``tick``, the clock-step *duration*.
+    jnp program on every platform). Not to be confused with ``tick``,
+    the clock-step *duration*.
 
     ``workers``: process count for the process backend; ``None`` uses all
     CPUs (capped at the batch size), ``0``/``1`` runs serially in-process
@@ -424,7 +440,9 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
     worker processes, ``"local"`` executes inline (tests), a callable is
     a custom ``Transport`` factory (the remote-host seam). Works with
     both backends (the jax backend fans its lane-chunk jobs across the
-    fleet) and composes with ``retry``/``faults``/``job_timeout``.
+    fleet) and composes with ``retry``/``faults``/``job_timeout``. Fleet
+    workers run JAX on the CPU, so on an accelerator host the jax
+    backend refuses ``transport`` (``ValueError``); use ``shard``.
 
     ``shard`` (jax backend only): run each lane batch as one
     ``jax.shard_map`` program over the local device mesh
@@ -440,6 +458,7 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
                          "spec.curves)")
     if shard and backend != "jax":
         raise ValueError("shard applies to backend='jax' only")
+    _refuse_fleet_on_accelerator(backend, transport)
     from repro.sim.faults import as_faults
 
     faults = as_faults(faults)
@@ -610,6 +629,7 @@ class SweepDriver:
             raise ValueError("record_series applies to backend='jax' only")
         if shard and backend != "jax":
             raise ValueError("shard applies to backend='jax' only")
+        _refuse_fleet_on_accelerator(backend, transport)
         from repro.sim.faults import as_faults
 
         self.backend = backend

@@ -193,6 +193,63 @@ def test_shard_map_bitwise_identical():
                                       err_msg=key)
 
 
+def test_shard_map_bitwise_identical_on_four_devices():
+    """``shard=True`` over 4 (virtual CPU) devices: the scan carry must
+    vary over the lane axis like the tick's output, and per-lane results
+    must equal the unsharded program's bitwise. Runs in a subprocess so
+    the forced device count does not leak into this test session."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    prog = textwrap.dedent("""
+        import jax, numpy as np
+        from repro.core.scenarios import expand_grid, pack_specs
+        from repro.sim.batched import simulate_packed
+
+        assert len(jax.devices()) == 4, jax.devices()
+        specs = expand_grid({"base": "III", "cache_tb": [10.0, 15.0, 20.0],
+                             "seed": 7, "days": 0.05, "n_files": 300})
+        grid = pack_specs(specs, tick=60.0)
+        plain = simulate_packed(grid)
+        for kw in ({}, {"lane_chunk": 2}):
+            sharded = simulate_packed(grid, shard=True, **kw)
+            assert set(sharded) == set(plain)
+            for key in plain:
+                np.testing.assert_array_equal(plain[key], sharded[key],
+                                              err_msg=key)
+        print("OK")
+    """)
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, timeout=600, env=env)
+    assert "OK" in res.stdout, res.stderr[-3000:]
+
+
+def test_jax_transport_refused_on_accelerator(monkeypatch):
+    """Fleet workers pin JAX to the CPU and a chip belongs to one
+    process, so on an accelerator host ``backend="jax"`` with a
+    ``transport`` raises (pointing at ``shard=True``) instead of quietly
+    simulating on the host CPU. The process backend's fleet is fine."""
+    from repro.kernels import registry
+    from repro.sim.sweep import SweepDriver
+
+    monkeypatch.setattr(registry, "_platform", lambda: "tpu")
+    spec = ScenarioSpec(days=0.05, n_files=300)
+    for transport in ("local", "subprocess"):
+        with pytest.raises(ValueError, match="shard=True"):
+            run_sweep([spec], backend="jax", transport=transport)
+        with pytest.raises(ValueError, match="fleet workers run on the CPU"):
+            SweepDriver(backend="jax", transport=transport)
+    assert run_sweep([spec], backend="process", transport="local",
+                     workers=1).ok
+    monkeypatch.setattr(registry, "_platform", lambda: "cpu")
+    SweepDriver(backend="jax", transport="local")  # CPU host: allowed
+
+
 def test_shard_excludes_devices_round_robin():
     import jax
 
@@ -420,13 +477,10 @@ def test_tick_impl_interpret_parity_small_grid(impl_grid):
 
 
 def test_tick_impl_auto_resolves_to_jnp_on_cpu(impl_grid):
-    """On a CPU host "auto" must be the jnp program *bitwise* — never a
-    silent interpret-mode fallback (registry resolution contract)."""
-    import jax
-
+    """"auto" must be the jnp program *bitwise* — never a silent
+    interpret-mode fallback (registry resolution contract). It resolves
+    to jnp on every platform, so this holds on an accelerator too."""
     _, out = impl_grid
-    if jax.default_backend() != "cpu":
-        pytest.skip("auto resolves to the compiled kernel on accelerators")
     for a, b in zip(out["jnp"].results, out["auto"].results):
         assert a.spec == b.spec
         assert a.metrics == b.metrics, a.spec.label

@@ -67,7 +67,8 @@ def test_local_dispatch_matches_global_multidevice():
         from repro.parallel.ctx import sharding_ctx
 
         cfg = get_smoke_config("olmoe_1b_7b").replace(capacity_factor=16.0)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         p = init_moe(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model),
                               dtype=cfg.dtype)

@@ -1,0 +1,9 @@
+"""device_idle: share of the traced window in which no operation ran on
+the device (1 - union of op intervals / window), from the profiler trace."""
+
+
+def read(record):
+    trace = record["trace"]
+    if trace is None or trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])

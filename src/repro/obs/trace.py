@@ -12,6 +12,12 @@ The tracer is **disabled by default**: an idle span is one attribute
 check and a no-op context manager, so library code can wrap hot phases
 unconditionally. The CLIs enable it when ``--trace-out`` is given.
 
+An enabled tracer also opens a ``jax.profiler.TraceAnnotation`` of the
+span's name around each span (where jax imports; it is imported at
+``enable``, never at module import). Under a JAX profiler session the
+span then lands on the trace's host plane, on the profiler's own clock,
+beside the device's operations.
+
 ``jax_device_profile`` is the optional deep-dive hook: when tracing is
 enabled and jax is importable it brackets the block with
 ``jax.profiler.start_trace``/``stop_trace`` (TensorBoard/XProf format,
@@ -30,8 +36,18 @@ import os
 import threading
 import time
 import uuid
-from contextlib import contextmanager
-from typing import Any, Dict, Iterable, List, Optional
+from contextlib import contextmanager, nullcontext
+from typing import Any, Callable, Dict, Iterable, List, Optional
+
+
+def _profiler_annotation() -> Optional[Callable]:
+    """``jax.profiler.TraceAnnotation``, or ``None`` where jax does not
+    import."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:
+        return None
+    return TraceAnnotation
 
 
 class Tracer:
@@ -45,6 +61,7 @@ class Tracer:
     def __init__(self, run_id: Optional[str] = None, enabled: bool = False):
         self.enabled = enabled
         self.run_id = run_id or uuid.uuid4().hex[:8]
+        self._annotation = _profiler_annotation() if enabled else None
         self._events: List[Dict[str, Any]] = []
         self._lock = threading.Lock()
 
@@ -52,6 +69,7 @@ class Tracer:
     def enable(self, run_id: Optional[str] = None) -> None:
         if run_id is not None:
             self.run_id = run_id
+        self._annotation = _profiler_annotation()
         self.enabled = True
 
     def disable(self) -> None:
@@ -69,14 +87,17 @@ class Tracer:
         ``args`` become the event's ``args`` payload (JSON-safe values
         only; non-serializable values are ``repr``-ed at dump time).
         Exceptions propagate; the span still closes and is annotated
-        with ``error=True``.
+        with ``error=True``. The block also runs inside a profiler
+        annotation of the same name (module docstring).
         """
         if not self.enabled:
             yield
             return
+        annotation = self._annotation
         t0 = time.perf_counter_ns()
         try:
-            yield
+            with annotation(name) if annotation else nullcontext():
+                yield
         except BaseException:
             args = dict(args, error=True)
             raise

@@ -97,6 +97,20 @@ ABSENT, IN_FLIGHT, PRESENT = 0, 1, 2
 #: it — a burst simply drains over the next few ticks.
 WAIT_ADMITS_PER_TICK = 4
 
+#: ``jax.named_scope`` of each phase of the tick, in tick order. Every
+#: operation traced inside a phase carries its scope in the compiled
+#: HLO's ``op_name`` metadata, so a profiler trace can be reduced by
+#: phase under names that outlive a refactor of the tick. ``series``
+#: exists only under ``record_series``.
+TICK_SCOPES = {
+    "transfer": "tick.transfer",  # transfer advance, billing, link slots
+    "migrate": "tick.migrate",    # deletions, the GCS gate, migrations
+    "submit": "tick.submit",      # job arrivals, pending -> ready
+    "waitq": "tick.waitq",        # waiting-queue admission (top-k)
+    "apply": "tick.apply",        # deferred scatters, GB-seconds
+    "series": "tick.series",      # per-tick series capture
+}
+
 #: Refinement passes of the shared-GCS admission gate. The reference
 #: engine's greedy scan admits every *individually* fitting candidate (a
 #: too-big file is skipped, not head-blocking); each prefix-sum pass over
@@ -175,6 +189,10 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
     so the per-tick cost stays O(S) and memory O(n_samples * S) per
     lane. With ``record=None`` the carry, the traced program, and the
     results are byte-for-byte the pre-capture ones.
+
+    Each phase of the tick, on both implementations, runs under its
+    ``TICK_SCOPES`` name; the scopes are metadata and leave the
+    program's operations unchanged.
     """
     use_kernel = impl.use_kernel
     interpret = impl.interpret
@@ -188,181 +206,184 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
         st = dict(state)
         site_rows = jnp.arange(S, dtype=jnp.int32)
 
-        # -- consumer snapshot (jobs submitted strictly before this tick
-        # that have not finished by ``now``; deletions run before
-        # submissions in the reference generator, so this tick's arrivals
-        # are excluded — their scatters land at the end of the tick).
-        no_cons = (st["pend_cnt"] == 0) & (st["fin_max"] <= now)
+        with jax.named_scope(TICK_SCOPES["transfer"]):
+            # -- consumer snapshot (jobs submitted strictly before this tick
+            # that have not finished by ``now``; deletions run before
+            # submissions in the reference generator, so this tick's arrivals
+            # are excluded — their scatters land at the end of the tick).
+            no_cons = (st["pend_cnt"] == 0) & (st["fin_max"] <= now)
 
-        # -- advance transfers one tick (the carousel tick math). A file
-        # only ever transfers on its own site's three links (link id =
-        # 3*site + type), so the per-link active counts are a one-hot
-        # reduction over the link-type axis with no scatter (XLA:CPU
-        # expands scatters into O(S·F)-trip sequential loops that
-        # dominated the tick before this formulation). The kernel path
-        # fuses the same math with the completion billing below in one
-        # per-site Pallas block (``lane_tick.transfer_tick``).
-        now_prev = now - dt
-        t_active = st["tr_slot"] & (st["tr_start"] <= now_prev + 0.5)
-        ltype = st["tr_link"] % 3  # 0 tape->disk, 1 gcs->disk, 2 disk->gcs
-        loc_onehot = ltype[:, :, None] == jnp.arange(3, dtype=jnp.int32)
+            # -- advance transfers one tick (the carousel tick math). A file
+            # only ever transfers on its own site's three links (link id =
+            # 3*site + type), so the per-link active counts are a one-hot
+            # reduction over the link-type axis with no scatter (XLA:CPU
+            # expands scatters into O(S·F)-trip sequential loops that
+            # dominated the tick before this formulation). The kernel path
+            # fuses the same math with the completion billing below in one
+            # per-site Pallas block (``lane_tick.transfer_tick``).
+            now_prev = now - dt
+            t_active = st["tr_slot"] & (st["tr_start"] <= now_prev + 0.5)
+            ltype = st["tr_link"] % 3  # 0 tape->disk, 1 gcs->disk, 2 disk->gcs
+            loc_onehot = ltype[:, :, None] == jnp.arange(3, dtype=jnp.int32)
 
-        def per_link(table):
-            """``table[tr_link]`` for every file row, as a select over the
-            row's link type instead of a gather. A file uses only its own
-            site's links. In XLA's cost model for a TPU v5e, five such
-            [S, F] gathers were about 94% of the paper-width tick's
-            memory traffic. Rows with no transfer yet hold link 0 and read
-            their own site's type-0 entry; every use masks those rows."""
-            t3 = table.reshape(S, 3)
-            return jnp.where(ltype == 0, t3[:, 0:1],
-                             jnp.where(ltype == 1, t3[:, 1:2], t3[:, 2:3]))
+            def per_link(table):
+                """``table[tr_link]`` for every file row, as a select over the
+                row's link type instead of a gather. A file uses only its own
+                site's links. In XLA's cost model for a TPU v5e, five such
+                [S, F] gathers were about 94% of the paper-width tick's
+                memory traffic. Rows with no transfer yet hold link 0 and read
+                their own site's type-0 entry; every use masks those rows."""
+                t3 = table.reshape(S, 3)
+                return jnp.where(ltype == 0, t3[:, 0:1],
+                                 jnp.where(ltype == 1, t3[:, 1:2], t3[:, 2:3]))
 
-        if use_kernel:
-            month_onehot = (jnp.arange(n_months, dtype=jnp.int32)
-                            == month).astype(jnp.float32)
-            (new_done, comp_f, tape_add, recall_add, mig_add,
-             egress_add, cls_a_add, cls_b_add) = lane_tick.transfer_tick(
-                st["tr_link"], t_active, st["tr_done"], st["tr_total"],
-                sizes, bw, mode, dt, month_onehot, interpret=interpret)
-            comp = comp_f > 0.5
-        else:
-            act_f = t_active.astype(jnp.float32)
-            counts = jnp.sum(act_f[:, :, None] * loc_onehot,
-                             axis=1).reshape(-1)  # [M], M = 3*S
-            bw_i = per_link(bw)
-            shared = bw_i / jnp.maximum(per_link(counts), 1.0)
-            rate = jnp.where(per_link(mode) > 0, bw_i, shared)
-            new_done = jnp.minimum(st["tr_total"],
-                                   st["tr_done"] + act_f * rate * dt)
-            comp = (new_done >= st["tr_total"]) & t_active
-        comp_tape = comp & (ltype == 0)
-        comp_recall = comp & (ltype == 1)
-        comp_mig = comp & (ltype == 2)
-        inbound = comp_tape | comp_recall
+            if use_kernel:
+                month_onehot = (jnp.arange(n_months, dtype=jnp.int32)
+                                == month).astype(jnp.float32)
+                (new_done, comp_f, tape_add, recall_add, mig_add,
+                 egress_add, cls_a_add, cls_b_add) = lane_tick.transfer_tick(
+                    st["tr_link"], t_active, st["tr_done"], st["tr_total"],
+                    sizes, bw, mode, dt, month_onehot, interpret=interpret)
+                comp = comp_f > 0.5
+            else:
+                act_f = t_active.astype(jnp.float32)
+                counts = jnp.sum(act_f[:, :, None] * loc_onehot,
+                                 axis=1).reshape(-1)  # [M], M = 3*S
+                bw_i = per_link(bw)
+                shared = bw_i / jnp.maximum(per_link(counts), 1.0)
+                rate = jnp.where(per_link(mode) > 0, bw_i, shared)
+                new_done = jnp.minimum(st["tr_total"],
+                                       st["tr_done"] + act_f * rate * dt)
+                comp = (new_done >= st["tr_total"]) & t_active
+            comp_tape = comp & (ltype == 0)
+            comp_recall = comp & (ltype == 1)
+            comp_mig = comp & (ltype == 2)
+            inbound = comp_tape | comp_recall
 
-        st["disk_state"] = jnp.where(inbound, PRESENT, st["disk_state"])
-        st["gcs_state"] = jnp.where(comp_mig, PRESENT, st["gcs_state"])
-        if use_kernel:  # billing deltas came fused out of the kernel
-            st["tape_b"] += tape_add
-            st["gcsdisk_b"] += recall_add
-            st["diskgcs_b"] += mig_add
-            st["egress_mo"] += egress_add
-            st["cls_a_mo"] += cls_a_add
-            st["cls_b_mo"] += cls_b_add
-        else:
-            st["tape_b"] += jnp.sum(sizes * comp_tape, axis=1)
-            st["gcsdisk_b"] += jnp.sum(sizes * comp_recall, axis=1)
-            recall_bytes = jnp.sum(sizes * comp_recall)
-            st["egress_mo"] = st["egress_mo"].at[month].add(recall_bytes)
-            st["cls_b_mo"] = st["cls_b_mo"].at[month].add(
-                jnp.sum(comp_recall).astype(jnp.float32))
-            st["diskgcs_b"] += jnp.sum(sizes * comp_mig, axis=1)
-            st["cls_a_mo"] = st["cls_a_mo"].at[month].add(
-                jnp.sum(comp_mig).astype(jnp.float32))
-        # migrated with no remaining consumer: drop the hot copy now
-        drop_hot = comp_mig & no_cons & (st["disk_state"] == PRESENT)
-        st["disk_used"] -= jnp.sum(sizes * drop_hot, axis=1)
-        st["disk_state"] = jnp.where(drop_hot, ABSENT, st["disk_state"])
-        st["tr_slot"] = st["tr_slot"] & ~comp
-        st["tr_done"] = jnp.where(comp, 0.0, new_done)
-        st["tr_total"] = jnp.where(comp, _INF, st["tr_total"])
-        st["tr_start"] = jnp.where(comp, _INF, st["tr_start"])
+            st["disk_state"] = jnp.where(inbound, PRESENT, st["disk_state"])
+            st["gcs_state"] = jnp.where(comp_mig, PRESENT, st["gcs_state"])
+            if use_kernel:  # billing deltas came fused out of the kernel
+                st["tape_b"] += tape_add
+                st["gcsdisk_b"] += recall_add
+                st["diskgcs_b"] += mig_add
+                st["egress_mo"] += egress_add
+                st["cls_a_mo"] += cls_a_add
+                st["cls_b_mo"] += cls_b_add
+            else:
+                st["tape_b"] += jnp.sum(sizes * comp_tape, axis=1)
+                st["gcsdisk_b"] += jnp.sum(sizes * comp_recall, axis=1)
+                recall_bytes = jnp.sum(sizes * comp_recall)
+                st["egress_mo"] = st["egress_mo"].at[month].add(recall_bytes)
+                st["cls_b_mo"] = st["cls_b_mo"].at[month].add(
+                    jnp.sum(comp_recall).astype(jnp.float32))
+                st["diskgcs_b"] += jnp.sum(sizes * comp_mig, axis=1)
+                st["cls_a_mo"] = st["cls_a_mo"].at[month].add(
+                    jnp.sum(comp_mig).astype(jnp.float32))
+            # migrated with no remaining consumer: drop the hot copy now
+            drop_hot = comp_mig & no_cons & (st["disk_state"] == PRESENT)
+            st["disk_used"] -= jnp.sum(sizes * drop_hot, axis=1)
+            st["disk_state"] = jnp.where(drop_hot, ABSENT, st["disk_state"])
+            st["tr_slot"] = st["tr_slot"] & ~comp
+            st["tr_done"] = jnp.where(comp, 0.0, new_done)
+            st["tr_total"] = jnp.where(comp, _INF, st["tr_total"])
+            st["tr_start"] = jnp.where(comp, _INF, st["tr_start"])
 
-        # arrived files resolve their pending jobs (ready is assigned in
-        # the pending step below with the same ``now``): the pending count
-        # folds into the analytic finish horizon.
-        resolve = inbound & (st["pend_cnt"] > 0)
-        st["fin_max"] = jnp.where(
-            resolve, jnp.maximum(st["fin_max"], now + st["pend_tail"]),
-            st["fin_max"])
-        st["pend_cnt"] = jnp.where(inbound, 0, st["pend_cnt"])
-        st["pend_tail"] = jnp.where(inbound, 0.0, st["pend_tail"])
+            # arrived files resolve their pending jobs (ready is assigned in
+            # the pending step below with the same ``now``): the pending count
+            # folds into the analytic finish horizon.
+            resolve = inbound & (st["pend_cnt"] > 0)
+            st["fin_max"] = jnp.where(
+                resolve, jnp.maximum(st["fin_max"], now + st["pend_tail"]),
+                st["fin_max"])
+            st["pend_cnt"] = jnp.where(inbound, 0, st["pend_cnt"])
+            st["pend_tail"] = jnp.where(inbound, 0.0, st["pend_tail"])
 
-        # -- link-slot FIFO admission (tickets are contiguous per link).
-        # Link-indexed counters live as [S, 3] matrices (site x link type)
-        # so every update is a static column slice, never a scatter.
-        occ3 = jnp.sum(st["tr_slot"].astype(jnp.float32)[:, :, None]
-                       * loc_onehot, axis=1)  # [S, 3] active-slot counts
-        occ = occ3.reshape(-1)
-        free = jnp.maximum(slots - occ, 0.0)
-        n_q = (st["lq_next"] - st["lq_serve"]).astype(jnp.float32)
-        admit = jnp.minimum(free, n_q).astype(jnp.int32)
-        new_serve = st["lq_serve"] + admit
-        adm_row = st["lq_queued"] & \
-            (st["lq_ticket"] < per_link(new_serve))
-        st["tr_slot"] = st["tr_slot"] | adm_row
-        st["tr_start"] = jnp.where(adm_row, now + per_link(latency),
-                                   st["tr_start"])
-        st["lq_queued"] = st["lq_queued"] & ~adm_row
-        st["lq_serve"] = new_serve
-        occ3 = (occ + admit.astype(jnp.float32)).reshape(S, 3)
-        lqn3 = st["lq_next"].reshape(S, 3)   # working [S, 3] views; the
-        lqs3 = st["lq_serve"].reshape(S, 3)  # flat [M] state is written
-        slots3 = slots.reshape(S, 3)         # back after the windows
-        lat3 = latency.reshape(S, 3)
+            # -- link-slot FIFO admission (tickets are contiguous per link).
+            # Link-indexed counters live as [S, 3] matrices (site x link type)
+            # so every update is a static column slice, never a scatter.
+            occ3 = jnp.sum(st["tr_slot"].astype(jnp.float32)[:, :, None]
+                           * loc_onehot, axis=1)  # [S, 3] active-slot counts
+            occ = occ3.reshape(-1)
+            free = jnp.maximum(slots - occ, 0.0)
+            n_q = (st["lq_next"] - st["lq_serve"]).astype(jnp.float32)
+            admit = jnp.minimum(free, n_q).astype(jnp.int32)
+            new_serve = st["lq_serve"] + admit
+            adm_row = st["lq_queued"] & \
+                (st["lq_ticket"] < per_link(new_serve))
+            st["tr_slot"] = st["tr_slot"] | adm_row
+            st["tr_start"] = jnp.where(adm_row, now + per_link(latency),
+                                       st["tr_start"])
+            st["lq_queued"] = st["lq_queued"] & ~adm_row
+            st["lq_serve"] = new_serve
+            occ3 = (occ + admit.astype(jnp.float32)).reshape(S, 3)
+            lqn3 = st["lq_next"].reshape(S, 3)   # working [S, 3] views; the
+            lqs3 = st["lq_serve"].reshape(S, 3)  # flat [M] state is written
+            slots3 = slots.reshape(S, 3)         # back after the windows
+            lat3 = latency.reshape(S, 3)
 
-        # -- hot-tier deletions + hot->cold migrations --------------------
-        limited = jnp.isfinite(disk_limit)[:, None]
-        cand = no_cons & (st["disk_state"] == PRESENT) & limited
-        gs = st["gcs_state"]
-        migratable = gcs_enabled & (gs == ABSENT) & (pop >= min_pop)
-        delete = cand & (~gcs_enabled | (gs == PRESENT)
-                         | ((gs == ABSENT) & ~(pop >= min_pop)))
-        want_mig = cand & migratable
-        # Shared GCS capacity: a prefix-sum admission gate over the
-        # site-major flattened candidate vector (one cumsum covers every
-        # site; earlier candidates' admissions are visible to later ones),
-        # refined over a few passes so a too-big blocker does not head-
-        # block the fitting candidates behind it. The kernel path runs
-        # each pass as one Pallas call over the sequential site grid,
-        # byte totals carried across site blocks and the previous
-        # pass's mask re-entering as an aliased input, fusing the
-        # end-of-tick GB-second integration; its blocked cumsum
-        # reassociates the float totals, so admission matches the jnp
-        # program statistically (capacity-boundary ties), not bitwise.
-        if use_kernel:
-            mig_f, gcs_used, gbsec_add = lane_tick.gcs_admit(
-                want_mig, sizes, st["gcs_used"], gcs_limit, dt,
-                month_onehot, n_passes=GCS_ADMIT_PASSES,
-                interpret=interpret)
-            mig = mig_f > 0.5
-        else:
-            want_flat = want_mig.reshape(-1)
-            sizes_flat = sizes.reshape(-1)
-            admitted_flat = jnp.zeros((S * F,), bool)
-            gcs_used = st["gcs_used"]
-            for _ in range(GCS_ADMIT_PASSES):
-                rem = want_flat & ~admitted_flat
-                csum = jnp.cumsum(sizes_flat * rem)
-                new = rem & (gcs_used + csum <= gcs_limit)
-                gcs_used = gcs_used + jnp.sum(sizes_flat * new)
-                admitted_flat = admitted_flat | new
-            mig = admitted_flat.reshape(S, F)
-        st["gcs_used"] = gcs_used
-        st["gcs_state"] = jnp.where(mig, IN_FLIGHT, gs)
-        st["disk_used"] -= jnp.sum(sizes * delete, axis=1)
-        st["disk_state"] = jnp.where(delete, ABSENT, st["disk_state"])
-        # submit migrations on each site's disk->gcs link (FIFO: direct
-        # slots only while the link queue is empty, overflow queues)
-        mlink = 3 * site_rows + 2  # [S]
-        rank = jnp.cumsum(mig.astype(jnp.float32), axis=1) - 1.0
-        q_empty = (lqn3[:, 2] == lqs3[:, 2])[:, None]
-        free_m = jnp.maximum(slots3[:, 2] - occ3[:, 2], 0.0)[:, None]
-        direct = mig & q_empty & (rank < free_m)
-        queued = mig & ~direct
-        qrank = jnp.cumsum(queued.astype(jnp.int32), axis=1) - 1
-        st["tr_slot"] = st["tr_slot"] | direct
-        st["tr_link"] = jnp.where(mig, mlink[:, None], st["tr_link"])
-        st["tr_total"] = jnp.where(mig, sizes, st["tr_total"])
-        st["tr_done"] = jnp.where(mig, 0.0, st["tr_done"])
-        st["tr_start"] = jnp.where(direct, now, st["tr_start"])
-        st["lq_ticket"] = jnp.where(
-            queued, lqn3[:, 2][:, None] + qrank, st["lq_ticket"])
-        st["lq_queued"] = st["lq_queued"] | queued
-        lqn3 = lqn3.at[:, 2].add(
-            jnp.sum(queued, axis=1).astype(jnp.int32))
-        occ3 = occ3.at[:, 2].add(jnp.sum(direct, axis=1).astype(jnp.float32))
+        with jax.named_scope(TICK_SCOPES["migrate"]):
+            # -- hot-tier deletions + hot->cold migrations --------------------
+            limited = jnp.isfinite(disk_limit)[:, None]
+            cand = no_cons & (st["disk_state"] == PRESENT) & limited
+            gs = st["gcs_state"]
+            migratable = gcs_enabled & (gs == ABSENT) & (pop >= min_pop)
+            delete = cand & (~gcs_enabled | (gs == PRESENT)
+                             | ((gs == ABSENT) & ~(pop >= min_pop)))
+            want_mig = cand & migratable
+            # Shared GCS capacity: a prefix-sum admission gate over the
+            # site-major flattened candidate vector (one cumsum covers every
+            # site; earlier candidates' admissions are visible to later ones),
+            # refined over a few passes so a too-big blocker does not head-
+            # block the fitting candidates behind it. The kernel path runs
+            # each pass as one Pallas call over the sequential site grid,
+            # byte totals carried across site blocks and the previous
+            # pass's mask re-entering as an aliased input, fusing the
+            # end-of-tick GB-second integration; its blocked cumsum
+            # reassociates the float totals, so admission matches the jnp
+            # program statistically (capacity-boundary ties), not bitwise.
+            if use_kernel:
+                mig_f, gcs_used, gbsec_add = lane_tick.gcs_admit(
+                    want_mig, sizes, st["gcs_used"], gcs_limit, dt,
+                    month_onehot, n_passes=GCS_ADMIT_PASSES,
+                    interpret=interpret)
+                mig = mig_f > 0.5
+            else:
+                want_flat = want_mig.reshape(-1)
+                sizes_flat = sizes.reshape(-1)
+                admitted_flat = jnp.zeros((S * F,), bool)
+                gcs_used = st["gcs_used"]
+                for _ in range(GCS_ADMIT_PASSES):
+                    rem = want_flat & ~admitted_flat
+                    csum = jnp.cumsum(sizes_flat * rem)
+                    new = rem & (gcs_used + csum <= gcs_limit)
+                    gcs_used = gcs_used + jnp.sum(sizes_flat * new)
+                    admitted_flat = admitted_flat | new
+                mig = admitted_flat.reshape(S, F)
+            st["gcs_used"] = gcs_used
+            st["gcs_state"] = jnp.where(mig, IN_FLIGHT, gs)
+            st["disk_used"] -= jnp.sum(sizes * delete, axis=1)
+            st["disk_state"] = jnp.where(delete, ABSENT, st["disk_state"])
+            # submit migrations on each site's disk->gcs link (FIFO: direct
+            # slots only while the link queue is empty, overflow queues)
+            mlink = 3 * site_rows + 2  # [S]
+            rank = jnp.cumsum(mig.astype(jnp.float32), axis=1) - 1.0
+            q_empty = (lqn3[:, 2] == lqs3[:, 2])[:, None]
+            free_m = jnp.maximum(slots3[:, 2] - occ3[:, 2], 0.0)[:, None]
+            direct = mig & q_empty & (rank < free_m)
+            queued = mig & ~direct
+            qrank = jnp.cumsum(queued.astype(jnp.int32), axis=1) - 1
+            st["tr_slot"] = st["tr_slot"] | direct
+            st["tr_link"] = jnp.where(mig, mlink[:, None], st["tr_link"])
+            st["tr_total"] = jnp.where(mig, sizes, st["tr_total"])
+            st["tr_done"] = jnp.where(mig, 0.0, st["tr_done"])
+            st["tr_start"] = jnp.where(direct, now, st["tr_start"])
+            st["lq_ticket"] = jnp.where(
+                queued, lqn3[:, 2][:, None] + qrank, st["lq_ticket"])
+            st["lq_queued"] = st["lq_queued"] | queued
+            lqn3 = lqn3.at[:, 2].add(
+                jnp.sum(queued, axis=1).astype(jnp.int32))
+            occ3 = occ3.at[:, 2].add(
+                jnp.sum(direct, axis=1).astype(jnp.float32))
 
         # =================================================================
         # Candidate-window planning, site-batched. This tick's job arrivals
@@ -419,201 +440,208 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
                              direct=direct, queued=queued, tstart=tstart,
                              lq_val=lq_val)
 
-        # -- group 1: job submissions for this tick (only the first arrival
-        # of a file starts its transfer; later same-tick jobs attach) -----
-        started = jnp.zeros((S, 0), bool)
-        g1_fids = jnp.zeros((S, 0), jnp.int32)
-        if K > 0:
-            ks = jnp.arange(K, dtype=jnp.int32)
-            jpos = st["ptr"][:, None] + ks[None, :]  # [S, K]
-            jid = jnp.minimum(jpos, J - 1)
-            valid = (jpos < J) & \
-                (jnp.take_along_axis(job_submit_tick, jid, axis=1) == t)
-            fids = jnp.take_along_axis(job_fid, jid, axis=1)
-            g1_fids = fids
-            # same[s, k, j]: an earlier valid window slot j < k carries the
-            # same file — slot k attaches instead of starting a transfer.
-            same = (fids[:, None, :] == fids[:, :, None]) \
-                & valid[:, None, :] & (ks[None, None, :] < ks[None, :, None])
-            first = valid & ~jnp.any(same, axis=2)
-            size = jnp.take_along_axis(sizes, fids, axis=1)
-            ds = jnp.take_along_axis(st["disk_state"], fids, axis=1)
-            ww = jnp.take_along_axis(st["wq_wait"], fids, axis=1)
-            tailw = jnp.take_along_axis(job_tail, jid, axis=1)
-            absent = first & (ds == ABSENT)
+        with jax.named_scope(TICK_SCOPES["submit"]):
+            # -- group 1: job submissions for this tick (only the first arrival
+            # of a file starts its transfer; later same-tick jobs attach) -----
+            started = jnp.zeros((S, 0), bool)
+            g1_fids = jnp.zeros((S, 0), jnp.int32)
+            if K > 0:
+                ks = jnp.arange(K, dtype=jnp.int32)
+                jpos = st["ptr"][:, None] + ks[None, :]  # [S, K]
+                jid = jnp.minimum(jpos, J - 1)
+                valid = (jpos < J) & \
+                    (jnp.take_along_axis(job_submit_tick, jid, axis=1) == t)
+                fids = jnp.take_along_axis(job_fid, jid, axis=1)
+                g1_fids = fids
+                # same[s, k, j]: an earlier valid window slot j < k carries the
+                # same file — slot k attaches instead of starting a transfer.
+                same = (fids[:, None, :] == fids[:, :, None]) \
+                    & valid[:, None, :] \
+                    & (ks[None, None, :] < ks[None, :, None])
+                first = valid & ~jnp.any(same, axis=2)
+                size = jnp.take_along_axis(sizes, fids, axis=1)
+                ds = jnp.take_along_axis(st["disk_state"], fids, axis=1)
+                ww = jnp.take_along_axis(st["wq_wait"], fids, axis=1)
+                tailw = jnp.take_along_axis(job_tail, jid, axis=1)
+                absent = first & (ds == ABSENT)
+                if use_kernel:
+                    started_f, extra = lane_tick.window_admit(
+                        absent, size, st["disk_used"], disk_limit,
+                        fifo=False, interpret=interpret)
+                    started = started_f > 0.5
+                else:
+                    started_cols = []
+                    extra = jnp.zeros((S,), jnp.float32)
+                    for k in range(K):  # prefix recurrence over the window;
+                        fit = st["disk_used"] + extra + size[:, k] \
+                            <= disk_limit   # all sites advance together
+                        st_k = absent[:, k] & fit
+                        started_cols.append(st_k)
+                        extra = extra + jnp.where(st_k, size[:, k], 0.0)
+                    started = jnp.stack(started_cols, axis=1)  # [S, K]
+                st["disk_used"] = st["disk_used"] + extra
+                to_wait = absent & ~started & ~ww
+                wrank = jnp.cumsum(to_wait.astype(jnp.int32), axis=1) - 1
+                occ3, plan = plan_links(fids, started, occ3)
+                plan["to_wait"] = to_wait
+                plan["wq_val"] = jnp.where(to_wait,
+                                           st["wq_next"][:, None] + wrank, 0)
+                st["wq_next"] = st["wq_next"] + \
+                    jnp.sum(to_wait, axis=1).astype(jnp.int32)
+                plan["stale"] = jnp.zeros_like(started)
+                # incremental consumer deltas: window jobs whose file is on
+                # disk are ready this tick (analytic finish now + tail); the
+                # rest join the pending pool on their file.
+                ready_now = valid & (ds == PRESENT)
+                plan["pend_add"] = valid & ~ready_now
+                plan["fin_val"] = jnp.where(ready_now, now + tailw, _NEG_INF)
+                plan["tail"] = tailw
+                plans.append(plan)
+            st["ptr"] = st["ptr"] + jobs_now
+
+        with jax.named_scope(TICK_SCOPES["waitq"]):
+            # -- group 2: waiting-queue admission — strict FIFO on the disk
+            # window; the head blocks admission until its file fits (§5.2).
+            # Planned from the pre-scatter queue state: entries started above
+            # (queue-jump) are excluded by fid comparison; entries enqueued
+            # above are not yet visible (they join next tick, matching a tail
+            # position in the FIFO).
+            tickets = jnp.where(st["wq_wait"], st["wq_ticket"], _BIG_TICKET)
+            neg, idx = jax.lax.top_k(-tickets, W)  # [S, W] lowest tickets
+            validw = neg > -_BIG_TICKET
+            jumped = jnp.zeros(idx.shape, bool)
+            if K > 0:
+                started_fid = jnp.where(started, g1_fids, -1)  # [S, K]
+                jumped = jnp.any(idx[:, :, None] == started_fid[:, None, :],
+                                 axis=2)
+            ds = jnp.take_along_axis(st["disk_state"], idx, axis=1)
+            stale = validw & ((ds != ABSENT) | jumped)
+            size = jnp.take_along_axis(sizes, idx, axis=1)
             if use_kernel:
-                started_f, extra = lane_tick.window_admit(
-                    absent, size, st["disk_used"], disk_limit,
-                    fifo=False, interpret=interpret)
-                started = started_f > 0.5
+                admitted_f, extra = lane_tick.window_admit(
+                    validw & ~stale, size, st["disk_used"], disk_limit,
+                    fifo=True, interpret=interpret)
+                admitted = admitted_f > 0.5
             else:
-                started_cols = []
+                adm_cols = []
                 extra = jnp.zeros((S,), jnp.float32)
-                for k in range(K):  # prefix recurrence over the window;
-                    fit = st["disk_used"] + extra + size[:, k] \
-                        <= disk_limit   # all sites advance together
-                    st_k = absent[:, k] & fit
-                    started_cols.append(st_k)
-                    extra = extra + jnp.where(st_k, size[:, k], 0.0)
-                started = jnp.stack(started_cols, axis=1)  # [S, K]
+                blocked = jnp.zeros((S,), bool)
+                for k in range(W):  # FIFO prefix recurrence, sites together
+                    fit = st["disk_used"] + extra + size[:, k] <= disk_limit
+                    live = validw[:, k] & ~stale[:, k]
+                    adm = live & fit & ~blocked
+                    blocked = blocked | (live & ~fit)
+                    adm_cols.append(adm)
+                    extra = extra + jnp.where(adm, size[:, k], 0.0)
+                admitted = jnp.stack(adm_cols, axis=1)  # [S, W]
             st["disk_used"] = st["disk_used"] + extra
-            to_wait = absent & ~started & ~ww
-            wrank = jnp.cumsum(to_wait.astype(jnp.int32), axis=1) - 1
-            occ3, plan = plan_links(fids, started, occ3)
-            plan["to_wait"] = to_wait
-            plan["wq_val"] = jnp.where(to_wait,
-                                       st["wq_next"][:, None] + wrank, 0)
-            st["wq_next"] = st["wq_next"] + \
-                jnp.sum(to_wait, axis=1).astype(jnp.int32)
-            plan["stale"] = jnp.zeros_like(started)
-            # incremental consumer deltas: window jobs whose file is on
-            # disk are ready this tick (analytic finish now + tail); the
-            # rest join the pending pool on their file.
-            ready_now = valid & (ds == PRESENT)
-            plan["pend_add"] = valid & ~ready_now
-            plan["fin_val"] = jnp.where(ready_now, now + tailw, _NEG_INF)
-            plan["tail"] = tailw
+            occ3, plan = plan_links(idx, admitted, occ3)
+            plan["stale"] = stale
             plans.append(plan)
-        st["ptr"] = st["ptr"] + jobs_now
 
-        # -- group 2: waiting-queue admission — strict FIFO on the disk
-        # window; the head blocks admission until its file fits (§5.2).
-        # Planned from the pre-scatter queue state: entries started above
-        # (queue-jump) are excluded by fid comparison; entries enqueued
-        # above are not yet visible (they join next tick, matching a tail
-        # position in the FIFO).
-        tickets = jnp.where(st["wq_wait"], st["wq_ticket"], _BIG_TICKET)
-        neg, idx = jax.lax.top_k(-tickets, W)  # [S, W] lowest tickets
-        validw = neg > -_BIG_TICKET
-        jumped = jnp.zeros(idx.shape, bool)
-        if K > 0:
-            started_fid = jnp.where(started, g1_fids, -1)  # [S, K]
-            jumped = jnp.any(idx[:, :, None] == started_fid[:, None, :],
-                             axis=2)
-        ds = jnp.take_along_axis(st["disk_state"], idx, axis=1)
-        stale = validw & ((ds != ABSENT) | jumped)
-        size = jnp.take_along_axis(sizes, idx, axis=1)
-        if use_kernel:
-            admitted_f, extra = lane_tick.window_admit(
-                validw & ~stale, size, st["disk_used"], disk_limit,
-                fifo=True, interpret=interpret)
-            admitted = admitted_f > 0.5
-        else:
-            adm_cols = []
-            extra = jnp.zeros((S,), jnp.float32)
-            blocked = jnp.zeros((S,), bool)
-            for k in range(W):  # FIFO prefix recurrence, sites together
-                fit = st["disk_used"] + extra + size[:, k] <= disk_limit
-                live = validw[:, k] & ~stale[:, k]
-                adm = live & fit & ~blocked
-                blocked = blocked | (live & ~fit)
-                adm_cols.append(adm)
-                extra = extra + jnp.where(adm, size[:, k], 0.0)
-            admitted = jnp.stack(adm_cols, axis=1)  # [S, W]
-        st["disk_used"] = st["disk_used"] + extra
-        occ3, plan = plan_links(idx, admitted, occ3)
-        plan["stale"] = stale
-        plans.append(plan)
+            st["lq_next"] = lqn3.reshape(-1)
 
-        st["lq_next"] = lqn3.reshape(-1)
+        with jax.named_scope(TICK_SCOPES["submit"]):
+            # -- pending jobs whose input is on disk enter queued -> running;
+            # completion is analytic (ready + download + duration). Planned
+            # starts only flip ABSENT -> IN_FLIGHT, so the pre-scatter
+            # disk_state is PRESENT-accurate here. ----------------------------
+            pending = (job_submit_tick <= t) & (st["job_ready"] >= _INF)
+            on_disk = jnp.take_along_axis(st["disk_state"], job_fid,
+                                          axis=1) == PRESENT
+            st["job_ready"] = jnp.where(pending & on_disk, now,
+                                         st["job_ready"])
 
-        # -- pending jobs whose input is on disk enter queued -> running;
-        # completion is analytic (ready + download + duration). Planned
-        # starts only flip ABSENT -> IN_FLIGHT, so the pre-scatter
-        # disk_state is PRESENT-accurate here. ----------------------------
-        pending = (job_submit_tick <= t) & (st["job_ready"] >= _INF)
-        on_disk = jnp.take_along_axis(st["disk_state"], job_fid,
-                                      axis=1) == PRESENT
-        st["job_ready"] = jnp.where(pending & on_disk, now, st["job_ready"])
+        with jax.named_scope(TICK_SCOPES["apply"]):
+            # -- apply the planned windows: one scatter per state array.
+            # XLA:CPU expands each scatter into a sequential per-row loop, so
+            # rows are kept to the minimum: transfer/link plans scatter over
+            # both windows; the submission-only fields (wait-queue joins and
+            # the incremental consumer counters) exist only in the K-window
+            # and scatter over a third of the rows.
+            def cat(key):
+                return jnp.concatenate([p[key].reshape(-1) for p in plans])
 
-        # -- apply the planned windows: one scatter per state array.
-        # XLA:CPU expands each scatter into a sequential per-row loop, so
-        # rows are kept to the minimum: transfer/link plans scatter over
-        # both windows; the submission-only fields (wait-queue joins and
-        # the incremental consumer counters) exist only in the K-window
-        # and scatter over a third of the rows.
-        def cat(key):
-            return jnp.concatenate([p[key].reshape(-1) for p in plans])
+            rows = cat("rows")
+            fire = cat("fire")
+            stale = cat("stale")
+            m_vec = cat("m_vec")
+            direct = cat("direct")
+            queued = cat("queued")
+            tstart = cat("tstart")
+            lq_val = cat("lq_val")
+            size_c = sizes.reshape(-1)[rows]
 
-        rows = cat("rows")
-        fire = cat("fire")
-        stale = cat("stale")
-        m_vec = cat("m_vec")
-        direct = cat("direct")
-        queued = cat("queued")
-        tstart = cat("tstart")
-        lq_val = cat("lq_val")
-        size_c = sizes.reshape(-1)[rows]
+            def flat(name, update):
+                st[name] = update(st[name].reshape(-1)).reshape(S, F)
 
-        def flat(name, update):
-            st[name] = update(st[name].reshape(-1)).reshape(S, F)
+            cur_link = st["tr_link"].reshape(-1)[rows]
+            cur_lqt = st["lq_ticket"].reshape(-1)[rows]
+            flat("disk_state", lambda a: a.at[rows].add(
+                jnp.where(fire, IN_FLIGHT - ABSENT, 0)))
+            # started/stale entries leave the wait queue (new waiters join in
+            # the K-window block below, preserving the min-before-max order)
+            flat("wq_wait", lambda a: a.at[rows].min(~(fire | stale)))
+            flat("tr_link", lambda a: a.at[rows].add(
+                jnp.where(fire, m_vec - cur_link, 0)))
+            flat("tr_total", lambda a: a.at[rows].min(
+                jnp.where(fire, size_c, _INF)))
+            flat("tr_slot", lambda a: a.at[rows].max(direct))
+            flat("tr_start", lambda a: a.at[rows].min(tstart))
+            flat("lq_ticket", lambda a: a.at[rows].add(
+                jnp.where(queued, lq_val - cur_lqt, 0)))
+            flat("lq_queued", lambda a: a.at[rows].max(queued))
 
-        cur_link = st["tr_link"].reshape(-1)[rows]
-        cur_lqt = st["lq_ticket"].reshape(-1)[rows]
-        flat("disk_state", lambda a: a.at[rows].add(
-            jnp.where(fire, IN_FLIGHT - ABSENT, 0)))
-        # started/stale entries leave the wait queue (new waiters join in
-        # the K-window block below, preserving the min-before-max order)
-        flat("wq_wait", lambda a: a.at[rows].min(~(fire | stale)))
-        flat("tr_link", lambda a: a.at[rows].add(
-            jnp.where(fire, m_vec - cur_link, 0)))
-        flat("tr_total", lambda a: a.at[rows].min(
-            jnp.where(fire, size_c, _INF)))
-        flat("tr_slot", lambda a: a.at[rows].max(direct))
-        flat("tr_start", lambda a: a.at[rows].min(tstart))
-        flat("lq_ticket", lambda a: a.at[rows].add(
-            jnp.where(queued, lq_val - cur_lqt, 0)))
-        flat("lq_queued", lambda a: a.at[rows].max(queued))
+            if K > 0:  # K-window-only scatters (wait-queue joins + consumers)
+                g1 = plans[0]
+                rows1 = g1["rows"].reshape(-1)
+                to_wait = g1["to_wait"].reshape(-1)
+                wq_val = g1["wq_val"].reshape(-1)
+                pend_add = g1["pend_add"].reshape(-1)
+                fin_val = g1["fin_val"].reshape(-1)
+                tail_c = g1["tail"].reshape(-1)
+                cur_wqt = st["wq_ticket"].reshape(-1)[rows1]
+                flat("wq_wait", lambda a: a.at[rows1].max(to_wait))
+                flat("wq_ticket", lambda a: a.at[rows1].add(
+                    jnp.where(to_wait, wq_val - cur_wqt, 0)))
+                # incremental consumer counters (visible from the next tick
+                # on, matching the reference's deletions-before-submissions)
+                flat("pend_cnt", lambda a: a.at[rows1].add(
+                    jnp.where(pend_add, 1, 0)))
+                flat("pend_tail", lambda a: a.at[rows1].max(
+                    jnp.where(pend_add, tail_c, 0.0)))
+                flat("fin_max", lambda a: a.at[rows1].max(fin_val))
 
-        if K > 0:  # K-window-only scatters (wait-queue joins + consumers)
-            g1 = plans[0]
-            rows1 = g1["rows"].reshape(-1)
-            to_wait = g1["to_wait"].reshape(-1)
-            wq_val = g1["wq_val"].reshape(-1)
-            pend_add = g1["pend_add"].reshape(-1)
-            fin_val = g1["fin_val"].reshape(-1)
-            tail_c = g1["tail"].reshape(-1)
-            cur_wqt = st["wq_ticket"].reshape(-1)[rows1]
-            flat("wq_wait", lambda a: a.at[rows1].max(to_wait))
-            flat("wq_ticket", lambda a: a.at[rows1].add(
-                jnp.where(to_wait, wq_val - cur_wqt, 0)))
-            # incremental consumer counters (visible from the next tick
-            # on, matching the reference's deletions-before-submissions)
-            flat("pend_cnt", lambda a: a.at[rows1].add(
-                jnp.where(pend_add, 1, 0)))
-            flat("pend_tail", lambda a: a.at[rows1].max(
-                jnp.where(pend_add, tail_c, 0.0)))
-            flat("fin_max", lambda a: a.at[rows1].max(fin_val))
+            # -- integrate stored cloud volume (GB-seconds) per month ---------
+            # (kernel path: fused into ``gcs_admit`` above — ``gcs_used`` is
+            # final for the tick once admission has run)
+            if use_kernel:
+                st["gbsec_mo"] += gbsec_add
+            else:
+                st["gbsec_mo"] = st["gbsec_mo"].at[month].add(
+                    st["gcs_used"] / 1e9 * dt)
 
-        # -- integrate stored cloud volume (GB-seconds) per month ---------
-        # (kernel path: fused into ``gcs_admit`` above — ``gcs_used`` is
-        # final for the tick once admission has run)
-        if use_kernel:
-            st["gbsec_mo"] += gbsec_add
-        else:
-            st["gbsec_mo"] = st["gbsec_mo"].at[month].add(
-                st["gcs_used"] / 1e9 * dt)
-
-        # -- opt-in series capture (end-of-tick observables) --------------
-        if record is not None:
-            stride, n_samples = record
-            idx = jnp.where(t % stride == 0, t // stride,
-                            jnp.int32(n_samples))
-            queue = jnp.sum(st["wq_wait"], axis=1).astype(jnp.float32)
-            running = jnp.sum(
-                (st["job_ready"] < _INF)
-                & (st["job_ready"] + job_tail > now),
-                axis=1).astype(jnp.float32)
-            active3 = jnp.sum(
-                st["tr_slot"].astype(jnp.float32)[:, :, None]
-                * ((st["tr_link"] % 3)[:, :, None]
-                   == jnp.arange(3, dtype=jnp.int32)), axis=1)  # [S, 3]
-            upd = jax.lax.dynamic_update_index_in_dim
-            st["ser_disk"] = upd(st["ser_disk"], st["disk_used"], idx, 0)
-            st["ser_gcs"] = upd(st["ser_gcs"], st["gcs_used"], idx, 0)
-            st["ser_queue"] = upd(st["ser_queue"], queue, idx, 0)
-            st["ser_run"] = upd(st["ser_run"], running, idx, 0)
-            st["ser_link"] = upd(st["ser_link"], active3, idx, 0)
+        with jax.named_scope(TICK_SCOPES["series"]):
+            # -- opt-in series capture (end-of-tick observables) --------------
+            if record is not None:
+                stride, n_samples = record
+                idx = jnp.where(t % stride == 0, t // stride,
+                                jnp.int32(n_samples))
+                queue = jnp.sum(st["wq_wait"], axis=1).astype(jnp.float32)
+                running = jnp.sum(
+                    (st["job_ready"] < _INF)
+                    & (st["job_ready"] + job_tail > now),
+                    axis=1).astype(jnp.float32)
+                active3 = jnp.sum(
+                    st["tr_slot"].astype(jnp.float32)[:, :, None]
+                    * ((st["tr_link"] % 3)[:, :, None]
+                       == jnp.arange(3, dtype=jnp.int32)), axis=1)  # [S, 3]
+                upd = jax.lax.dynamic_update_index_in_dim
+                st["ser_disk"] = upd(st["ser_disk"], st["disk_used"], idx, 0)
+                st["ser_gcs"] = upd(st["ser_gcs"], st["gcs_used"], idx, 0)
+                st["ser_queue"] = upd(st["ser_queue"], queue, idx, 0)
+                st["ser_run"] = upd(st["ser_run"], running, idx, 0)
+                st["ser_link"] = upd(st["ser_link"], active3, idx, 0)
         return st, None
 
     def post_fn(st, lane, horizon):
@@ -1242,14 +1270,15 @@ def run_sweep_jax(specs: Sequence["ScenarioSpec"], tick: float = 10.0,
     ok_sis = [si for si in range(grid.n_specs)
               if int(grid.lane_of[si]) not in missing]
     results: List[ScenarioResult] = []
-    for si in ok_sis:
-        r = _lane_result(grid, out, si, wall / max(len(ok_sis), 1))
-        if capture:
-            r.series = {name: ts.summary() for name, ts in
-                        series_from_capture(grid, out, si,
-                                            record_series).items()}
-        results.append(r)
-        if progress is not None:
-            progress(len(results), len(ok_sis), results[-1])
+    with tracer.span("fold_results", specs=len(ok_sis)):
+        for si in ok_sis:
+            r = _lane_result(grid, out, si, wall / max(len(ok_sis), 1))
+            if capture:
+                r.series = {name: ts.summary() for name, ts in
+                            series_from_capture(grid, out, si,
+                                                record_series).items()}
+            results.append(r)
+            if progress is not None:
+                progress(len(results), len(ok_sis), results[-1])
     return SweepResult(results=results, wall_s=wall,
                        failures=registry.failures() if registry else [])

@@ -1,6 +1,9 @@
 """Unit tests for the ``repro.obs`` telemetry layer (ISSUE 8)."""
 
 import json
+import os
+import subprocess
+import sys
 
 import pytest
 
@@ -161,6 +164,68 @@ class TestTracer:
 
     def test_global_tracer_disabled_by_default(self):
         assert get_tracer() is get_tracer()
+
+    @pytest.mark.parametrize("switch", ["enabled", "never", "disabled"])
+    def test_span_opens_a_profiler_annotation_only_when_enabled(
+            self, monkeypatch, switch):
+        import jax.profiler
+
+        opened = []
+
+        class Counting:
+            def __init__(self, name, **kwargs):
+                opened.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        tr = Tracer()
+        if switch != "never":
+            tr.enable()
+        if switch == "disabled":
+            tr.disable()
+        with tr.span("pack_specs"):
+            with tr.span("simulate_packed", lanes=2):
+                pass
+        expected = ["pack_specs", "simulate_packed"]
+        assert opened == (expected if switch == "enabled" else [])
+        # the inner span closes, and is recorded, first
+        assert [e["name"] for e in tr.events] == (
+            expected[::-1] if switch == "enabled" else [])
+
+    def test_importing_repro_obs_leaves_jax_unimported(self):
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import sys, repro.obs; print('jax' in sys.modules)"],
+            env=env, capture_output=True, text=True, check=True)
+        assert out.stdout.strip() == "False"
+
+    def test_batched_sweep_spans_pack_dispatch_and_fold(self):
+        from repro.core.scenarios import ScenarioSpec
+        from repro.sim.batched import run_sweep_jax
+
+        tr = get_tracer()
+        tr.reset()
+        tr.enable()
+        try:
+            res = run_sweep_jax([ScenarioSpec(base="III", days=0.02,
+                                              n_files=200, seed=s)
+                                 for s in (1, 2)], tick=60.0)
+        finally:
+            tr.disable()
+        spans = {e["name"]: e for e in tr.events if e["ph"] == "X"}
+        tr.reset()
+        assert res.ok and len(res.results) == 2
+        fold = spans["fold_results"]
+        assert fold["args"]["specs"] == 2
+        assert fold["ts"] >= spans["simulate_packed"]["ts"] \
+            + spans["simulate_packed"]["dur"] - 1
 
     def test_jax_device_profile_noop_when_disabled(self):
         # tracer disabled -> silent no-op even with a logdir

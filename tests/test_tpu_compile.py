@@ -12,6 +12,7 @@ windows are ``pack_specs``'s buckets for that horizon.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -95,6 +96,34 @@ def test_jnp_grid_program_compiles_for_one_v5e(topo, paper_grid,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES, mem
+
+
+@pytest.fixture(scope="module")
+def f20k_grid():
+    """cfg III at 20,000 files per site, 2 lanes, 0.25 days at 60 s."""
+    specs = expand_grid({"base": "III", "n_files": 20_000, "days": 0.25,
+                         "seed": [0, 1]})
+    return pack_specs(specs, tick=60.0)
+
+
+@pytest.mark.parametrize("target", ["cpu", "v5e"])
+def test_grid_program_hlo_names_every_tick_phase(target, request, f20k_grid,
+                                                 no_compile_cache):
+    """Each phase's ``jax.named_scope`` reaches the compiled program's
+    ``op_name`` metadata, where a profiler trace can be mapped to it."""
+    import jax
+
+    grid = f20k_grid
+    device = (request.getfixturevalue("topo").devices[0] if target == "v5e"
+              else jax.devices("cpu")[0])
+    one = SingleDeviceSharding(device)
+    program = batched._grid_program.__wrapped__(
+        len(grid.site_names), grid.max_jobs_per_tick, grid.n_months, "jnp")
+    hlo = program.lower(*_arg_shapes(grid, one, one)).compile().as_text()
+    found = set(re.findall(r'op_name="[^"]*?/(tick\.\w+)[/"]', hlo))
+    # ``tick.series`` exists only under ``record_series``
+    assert found == {scope for phase, scope in batched.TICK_SCOPES.items()
+                     if phase != "series"}
 
 
 def test_shard_program_compiles_over_four_v5e_without_collectives(

@@ -106,7 +106,7 @@ TICK_SCOPES = {
     "transfer": "tick.transfer",  # transfer advance, billing, link slots
     "migrate": "tick.migrate",    # deletions, the GCS gate, migrations
     "submit": "tick.submit",      # job arrivals, pending -> ready
-    "waitq": "tick.waitq",        # waiting-queue admission (top-k)
+    "waitq": "tick.waitq",        # waiting-queue admission (W head passes)
     "apply": "tick.apply",        # deferred scatters, GB-seconds
     "series": "tick.series",      # per-tick series capture
 }
@@ -127,6 +127,33 @@ GCS_ADMIT_PASSES = 3
 _INF = jnp.float32(jnp.inf)
 _NEG_INF = jnp.float32(-jnp.inf)
 _BIG_TICKET = jnp.int32(2 ** 30)
+
+#: Masks a queue head already taken, above every ticket and ``_BIG_TICKET``.
+_TAKEN_TICKET = jnp.int32(np.iinfo(np.int32).max)
+
+
+def _queue_heads(tickets, W: int):
+    """The W lowest tickets along the last axis, as ``jax.lax.top_k(-tickets,
+    W)`` gives them (negated values, then indices; equal tickets in index
+    order), from W masked argmin passes.
+
+    ``top_k`` lowers to a full sort of the file axis on the TPU, which
+    the W=4 heads of a 10^6-file plane do not need: each pass is one
+    reduction over the plane. ``argmin`` returns the first index of the
+    minimum, and a taken slot is masked above ``_BIG_TICKET``, so
+    empty slots (``_BIG_TICKET``) come out in index order as in ``top_k``.
+    """
+    col = jax.lax.broadcasted_iota(jnp.int32, tickets.shape,
+                                   tickets.ndim - 1)
+    left = tickets
+    idx = []
+    for _ in range(W):
+        i = jnp.argmin(left, axis=-1).astype(jnp.int32)
+        left = jnp.where(col == i[..., None], _TAKEN_TICKET, left)
+        idx.append(i)
+    idx = jnp.stack(idx, axis=-1)
+    return -jnp.take_along_axis(tickets, idx, axis=-1), idx
+
 
 #: Per-site link-type order of the captured link-activity series (the
 #: ``3 * site + type`` link-id layout).
@@ -507,7 +534,7 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
             # above are not yet visible (they join next tick, matching a tail
             # position in the FIFO).
             tickets = jnp.where(st["wq_wait"], st["wq_ticket"], _BIG_TICKET)
-            neg, idx = jax.lax.top_k(-tickets, W)  # [S, W] lowest tickets
+            neg, idx = _queue_heads(tickets, W)  # [S, W] lowest tickets
             validw = neg > -_BIG_TICKET
             jumped = jnp.zeros(idx.shape, bool)
             if K > 0:

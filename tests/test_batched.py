@@ -8,6 +8,7 @@ per-lane tolerance is the paper's Table 2 validation tolerance (5%), the
 same bar the reference engine itself is held to against the paper.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -19,7 +20,13 @@ from repro.core.scenarios import (
     pack_specs,
     with_seeds,
 )
-from repro.sim.batched import simulate_packed
+from repro.sim import batched
+from repro.sim.batched import (
+    _BIG_TICKET,
+    WAIT_ADMITS_PER_TICK,
+    _queue_heads,
+    simulate_packed,
+)
 from repro.sim.sweep import run_sweep
 
 # Table 2 validation tolerance (fractional): the §4.2 bar for "the
@@ -124,6 +131,81 @@ def test_pack_specs_rejects_nonuniform_and_curves():
 def test_run_sweep_rejects_unknown_backend():
     with pytest.raises(ValueError, match="backend"):
         run_sweep([ScenarioSpec(**TINY)], backend="fortran")
+
+
+# ------------------------------------------------- waiting-queue heads
+def _ticket_plane(rng, shape, n_wait, low=0):
+    """A ticket plane as the tick builds it: ``n_wait`` distinct tickets in
+    ``[low, 2**30)`` at random files of each row, ``_BIG_TICKET`` elsewhere."""
+    t = np.full(shape, int(_BIG_TICKET), np.int32)
+    for row in t.reshape(-1, shape[-1]):
+        at = rng.choice(shape[-1], n_wait, replace=False)
+        row[at] = low + rng.choice(2 ** 30 - low, n_wait, replace=False)
+    return t
+
+
+W = WAIT_ADMITS_PER_TICK
+F_HEADS = 4096
+HEAD_CASES = {
+    "no_waiters": lambda r: _ticket_plane(r, (2, F_HEADS), 0),
+    "one_waiter": lambda r: _ticket_plane(r, (2, F_HEADS), 1),
+    "w_minus_1_waiters": lambda r: _ticket_plane(r, (2, F_HEADS), W - 1),
+    "w_waiters": lambda r: _ticket_plane(r, (2, F_HEADS), W),
+    "many_waiters": lambda r: _ticket_plane(r, (2, F_HEADS), 1500),
+    "every_file_waits": lambda r: _ticket_plane(r, (2, F_HEADS), F_HEADS),
+    "rows_differ": lambda r: np.concatenate(
+        [_ticket_plane(r, (1, F_HEADS), n) for n in (0, 2, W, 300)]),
+    # every ticket of [2**30 - 3000, 2**30 - 1], the largest one included
+    "tickets_below_big": lambda r: _ticket_plane(
+        r, (2, F_HEADS), 3000, low=2 ** 30 - 3000),
+    "equal_tickets": lambda r: np.where(
+        r.random((2, F_HEADS)) < 0.01, 7, int(_BIG_TICKET)).astype(np.int32),
+    "lanes_vmapped": lambda r: _ticket_plane(r, (3, 2, F_HEADS), 9),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HEAD_CASES))
+def test_queue_heads_equal_top_k(case):
+    """The W masked argmin passes give what ``top_k(-tickets, W)`` gives,
+    values and indices bitwise, empty slots and equal keys included."""
+    t = HEAD_CASES[case](np.random.default_rng(0))
+    assert t.dtype == np.int32 and t.max() <= int(_BIG_TICKET)
+    if t.ndim == 3:  # [lanes, sites, files], as the grid program runs it
+        got = jax.vmap(lambda x: _queue_heads(x, W))(t)
+    else:
+        got = jax.jit(_queue_heads, static_argnums=1)(t, W)
+    want = jax.lax.top_k(-t, W)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("tick_impl", ["jnp", "pallas_interpret"])
+def test_queue_heads_program_bitwise_equal_to_top_k(monkeypatch,
+                                                    tick_impl):
+    """The whole grid program, with its queue heads taken by ``top_k``
+    and by ``_queue_heads``, gives bitwise equal per-lane outputs on a
+    small disk whose waiting queue runs far deeper than W."""
+    specs = with_seeds([ScenarioSpec(base=b, cache_tb=1.0, **QUICK)
+                        for b in ("III", "II")], 2)
+    grid = pack_specs(specs, tick=60.0)
+    batched._grid_program.cache_clear()
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(batched, "_queue_heads",
+                      lambda t, w: jax.lax.top_k(-t, w))
+            by_top_k = simulate_packed(grid, tick_impl=tick_impl,
+                                       record_series=True)
+        batched._grid_program.cache_clear()
+        heads = simulate_packed(grid, tick_impl=tick_impl,
+                                record_series=True)
+    finally:
+        batched._grid_program.cache_clear()
+    assert heads["ser_queue"].max() > 10 * W
+    assert set(heads) == set(by_top_k)
+    for key in heads:
+        np.testing.assert_array_equal(heads[key], by_top_k[key],
+                                      err_msg=key)
 
 
 # ------------------------------------------- lane chunking & shape buckets

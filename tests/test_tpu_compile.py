@@ -106,6 +106,14 @@ def f20k_grid():
     return pack_specs(specs, tick=60.0)
 
 
+def _f20k_hlo(grid, device):
+    """The jnp grid program for ``grid``, compiled for one ``device``."""
+    one = SingleDeviceSharding(device)
+    program = batched._grid_program.__wrapped__(
+        len(grid.site_names), grid.max_jobs_per_tick, grid.n_months, "jnp")
+    return program.lower(*_arg_shapes(grid, one, one)).compile().as_text()
+
+
 @pytest.mark.parametrize("target", ["cpu", "v5e"])
 def test_grid_program_hlo_names_every_tick_phase(target, request, f20k_grid,
                                                  no_compile_cache):
@@ -113,17 +121,29 @@ def test_grid_program_hlo_names_every_tick_phase(target, request, f20k_grid,
     ``op_name`` metadata, where a profiler trace can be mapped to it."""
     import jax
 
-    grid = f20k_grid
     device = (request.getfixturevalue("topo").devices[0] if target == "v5e"
               else jax.devices("cpu")[0])
-    one = SingleDeviceSharding(device)
-    program = batched._grid_program.__wrapped__(
-        len(grid.site_names), grid.max_jobs_per_tick, grid.n_months, "jnp")
-    hlo = program.lower(*_arg_shapes(grid, one, one)).compile().as_text()
+    hlo = _f20k_hlo(f20k_grid, device)
     found = set(re.findall(r'op_name="[^"]*?/(tick\.\w+)[/"]', hlo))
     # ``tick.series`` exists only under ``record_series``
     assert found == {scope for phase, scope in batched.TICK_SCOPES.items()
                      if phase != "series"}
+
+
+@pytest.mark.parametrize("target", ["cpu", "v5e"])
+def test_grid_program_selects_queue_heads_without_a_sort(
+        target, request, f20k_grid, no_compile_cache):
+    """The waiting queue's W heads are W reductions over the ticket
+    plane: the compiled program holds no sort and no top-k, and the
+    ``tick.waitq`` phase still holds ops."""
+    import jax
+
+    device = (request.getfixturevalue("topo").devices[0] if target == "v5e"
+              else jax.devices("cpu")[0])
+    hlo = _f20k_hlo(f20k_grid, device)
+    assert " sort(" not in hlo
+    assert not re.search(r"(?i)top_?k", hlo)
+    assert re.search(r'op_name="[^"]*?/tick\.waitq[/"]', hlo)
 
 
 def test_shard_program_compiles_over_four_v5e_without_collectives(

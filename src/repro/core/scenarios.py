@@ -243,8 +243,10 @@ def strip_seed(spec: ScenarioSpec) -> ScenarioSpec:
 #: the meaning of a stored payload changes — simulation dynamics, metric
 #: definitions, the monthly-totals billing contract — and every stale
 #: entry becomes unreachable (new keys) *and* rejected on direct reads
-#: (entry-side version check), forcing recomputation.
-RESULT_SCHEMA_VERSION = 1
+#: (entry-side version check), forcing recomputation. Version 2: the
+#: batched engine's cloud admission gate became first fit, which changes
+#: every lane under a bucket quota that binds.
+RESULT_SCHEMA_VERSION = 2
 
 
 def engine_fingerprint(backend: str = "process",

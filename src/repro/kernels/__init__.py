@@ -17,8 +17,8 @@ after their one-release deprecation window).
   blocks with sequential-grid accumulation.
 - ``lane_tick``: the batched sweep engine's fused tick — the carousel
   transfer math + completion billing per site block, the shared-GCS
-  prefix-sum admission scan (refinement passes as a sequential grid
-  axis), and the K/W candidate-window prefix recurrences; lane-blocked
+  first-fit admission gate (one sequential site-grid call per pass),
+  and the K/W candidate-window prefix recurrences; lane-blocked
   via ``vmap`` (the batch axis becomes a leading grid dimension).
 - ``flash_attention``: blocked online-softmax attention (128x128 MXU
   tiles, GQA-aware, causal + sliding-window masks).

@@ -13,20 +13,22 @@ the ``tick_impl`` registry (``repro.kernels.registry``):
   private to its row (link id = 3*site + type), so per-link counts never
   cross blocks and the whole tick is block-local one-hot matmuls
   (``carousel_update`` design notes: gathers become MXU ``dot``s).
-- ``gcs_admit_pass_kernel``: the shared-GCS prefix-sum admission gate.
-  The jnp program runs ``GCS_ADMIT_PASSES`` passes of a *global* cumsum
-  over the site-major flattened candidate vector; here each pass is one
-  ``pallas_call`` over the sequential site grid, with the running byte
-  totals carried across site blocks in a small VMEM-resident carry ref
-  and the previous pass's admitted mask re-entering as a true (aliased)
-  input, fused with the end-of-tick GB-second storage integration.
-  (Passes cannot share one grid: compiled Pallas only preserves an
-  output window's VMEM contents across *consecutive* grid steps on the
-  same block, and a ``(passes, S)`` grid revisits each site block
-  non-consecutively.) The blocked cumsum reassociates the float pass
-  totals, so admission can differ from the jnp oracle by
-  capacity-boundary ties — statistical (Table-2) parity, not bitwise;
-  see ``docs/simulation.md``.
+- ``gcs_admit_pass_kernel``: one pass of the shared-GCS first-fit
+  admission gate (``repro.sim.batched._gcs_first_fit``): candidates
+  larger than the room left are dropped, then the prefix of the rest
+  whose cumsum fits is admitted. Each pass is one ``pallas_call`` over
+  the sequential site grid, the running cumsum carried across site
+  blocks in a small VMEM-resident carry ref and the previous pass's
+  admitted mask re-entering as a true (aliased) input, fused with the
+  end-of-tick GB-second storage integration; ``_gcs_first_fit``'s own
+  ``lax.while_loop`` runs it as each pass while a fitting candidate is
+  left, so the rule and the loop live in one place. (Passes cannot
+  share one grid: compiled Pallas only preserves an output window's
+  VMEM contents across *consecutive* grid steps on the same block, and
+  a ``(passes, S)`` grid revisits each site block non-consecutively.) The
+  blocked cumsum reassociates the float pass totals, so admission can
+  differ from the jnp program by capacity-boundary ties — statistical
+  (Table-2) parity, not bitwise; see ``docs/simulation.md``.
 - ``window_kernel``: the [S, K] job-arrival and [S, W] waiting-queue
   admission windows — C-step prefix recurrences (later candidates see
   earlier reservations; the wait queue additionally head-blocks) over
@@ -194,7 +196,7 @@ def transfer_tick(link_id, active, done, total, sizes, bw, mode, dt,
 def gcs_admit_pass_kernel(want_ref, sizes_ref, adm_in_ref, used0_ref,
                           limit_ref, dt_ref, month_ref,
                           adm_ref, used_ref, gbsec_ref, carry_ref):
-    """One refinement pass. Grid: (S,) sequential.
+    """One first-fit pass. Grid: (S,) sequential.
 
     ``adm_in_ref`` is the previous pass's admitted mask entering as a
     true input (buffer-aliased onto ``adm_ref``): each site block is
@@ -202,11 +204,12 @@ def gcs_admit_pass_kernel(want_ref, sizes_ref, adm_in_ref, used0_ref,
     after intervening blocks — compiled Pallas only guarantees VMEM
     persistence across *consecutive* grid steps on the same block.
     ``used0_ref`` is the pass-start occupancy, frozen for the whole pass
-    exactly like the jnp oracle's ``gcs_used``. ``carry_ref`` is a
-    2-slot accumulator (every step maps to the same block, hence
-    persistent; written as an output the caller discards): [0] bytes
-    admitted within this pass, [1] running candidate cumsum carried
-    across site blocks (the blocked image of the jnp global cumsum)."""
+    exactly like the jnp program's, and sets the room; candidates larger
+    than the room are left out of the pass. ``carry_ref`` is a 2-slot
+    accumulator (every step maps to the same block, hence persistent;
+    written as an output the caller discards): [0] bytes admitted within
+    this pass, [1] running candidate cumsum carried across site blocks
+    (the blocked image of the jnp global cumsum)."""
     s = pl.program_id(0)
 
     @pl.when(s == 0)
@@ -215,12 +218,12 @@ def gcs_admit_pass_kernel(want_ref, sizes_ref, adm_in_ref, used0_ref,
         carry_ref[1] = 0.0
 
     adm_prev = adm_in_ref[...]
-    want = want_ref[...] > 0.5
-    rem = want & ~(adm_prev > 0.5)
-    remf = rem.astype(jnp.float32)
     sz = sizes_ref[...]
+    room = limit_ref[0] - used0_ref[0]
+    rem = (want_ref[...] > 0.5) & ~(adm_prev > 0.5) & (sz <= room)
+    remf = rem.astype(jnp.float32)
     csum = jnp.cumsum(sz * remf, axis=-1) + carry_ref[1]
-    new = rem & (used0_ref[0] + csum <= limit_ref[0])
+    new = rem & (csum <= room)
     newf = new.astype(jnp.float32)
     adm_ref[...] = jnp.maximum(adm_prev, newf)
     carry_ref[0] += jnp.sum(sz * newf)
@@ -233,27 +236,36 @@ def gcs_admit_pass_kernel(want_ref, sizes_ref, adm_in_ref, used0_ref,
 
 
 def gcs_admit(want, sizes, gcs_used, gcs_limit, dt, month_onehot,
-              n_passes: int, interpret: Optional[bool] = None):
-    """Shared-capacity admission over a lane's [S, F] candidate plane.
+              first_fit, interpret: Optional[bool] = None):
+    """First-fit shared-capacity admission over a lane's [S, F] candidate
+    plane: ``first_fit`` (``repro.sim.batched._gcs_first_fit``, which
+    holds the gate's rule and its loop) run with a Pallas call as each
+    pass.
 
     want: [S, F] bool migration candidates; sizes: [S, F] f32 bytes;
     gcs_used/gcs_limit: f32 scalars; dt: f32 scalar tick length;
-    month_onehot: [n_months] f32; n_passes: refinement passes (static).
+    month_onehot: [n_months] f32.
 
-    Returns ``(admitted [S, F] f32 mask, gcs_used' f32 scalar,
-    gbsec_mo_delta [n_months])`` — the third output is the fused
-    ``gcs_used'/1e9*dt`` month-bucketed GB-second integration.
+    Returns ``(admitted [S, F] bool, gcs_used' f32 scalar,
+    gbsec_mo_delta [n_months], passes int32)`` — the third output is the
+    ``gcs_used'/1e9*dt`` month-bucketed GB-second integration, fused
+    into the last pass (computed here when no pass runs).
 
-    Each pass is one ``pallas_call`` (see ``gcs_admit_pass_kernel``);
-    the admitted mask and the pass-start occupancy flow between passes
-    as regular JAX values, the mask donated back in via
-    ``input_output_aliases``.
+    Each pass is one ``pallas_call`` (see ``gcs_admit_pass_kernel``) over
+    the loop's candidates that fit the room; the admitted mask and the
+    pass-start occupancy flow between passes as loop values, the mask
+    donated back in via ``input_output_aliases``.
     """
     if interpret is None:
         interpret = default_interpret()
     S, F = want.shape
     n_months = month_onehot.shape[0]
     fp = F + (-F) % F_BLOCK
+    wantp = _pad_f(want, fp)
+    sizesf = _pad_f(sizes, fp)
+    limit = jnp.reshape(gcs_limit, (1,)).astype(jnp.float32)
+    dtv = jnp.reshape(dt, (1,)).astype(jnp.float32)
+    monthf = month_onehot.astype(jnp.float32)
     row = pl.BlockSpec((1, fp), lambda s: (s, 0))
     one = pl.BlockSpec((1,), lambda s: (0,))
     months = pl.BlockSpec((n_months,), lambda s: (0,))
@@ -271,18 +283,17 @@ def gcs_admit(want, sizes, gcs_used, gcs_limit, dt, month_onehot,
         input_output_aliases={2: 0},
         interpret=interpret,
     )
-    wantf = _pad_f(want.astype(jnp.float32), fp)
-    sizesf = _pad_f(sizes, fp)
-    limit = jnp.reshape(gcs_limit, (1,)).astype(jnp.float32)
-    dtv = jnp.reshape(dt, (1,)).astype(jnp.float32)
-    monthf = month_onehot.astype(jnp.float32)
-    admitted = jnp.zeros((S, fp), jnp.float32)
-    used = jnp.reshape(gcs_used, (1,)).astype(jnp.float32)
-    gbsec = monthf * (used[0] / 1e9 * dtv[0])  # n_passes == 0 degenerate
-    for _ in range(n_passes):
+
+    def gate_pass(admitted, used, rem, _gbsec):
         admitted, used, gbsec, _carry = admit_pass(
-            wantf, sizesf, admitted, used, limit, dtv, monthf)
-    return admitted[:, :F], used[0], gbsec
+            rem.astype(jnp.float32), sizesf, admitted.astype(jnp.float32),
+            jnp.reshape(used, (1,)), limit, dtv, monthf)
+        return admitted > 0.5, used[0], gbsec
+
+    gbsec = monthf * (gcs_used / 1e9 * dtv[0])  # the value with no pass
+    admitted, used, passes, gbsec = first_fit(wantp, sizesf, gcs_used,
+                                              gcs_limit, gate_pass, gbsec)
+    return admitted[:, :F], used, gbsec, passes
 
 
 # ---------------------------------------------------------------------------

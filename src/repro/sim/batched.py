@@ -111,19 +111,6 @@ TICK_SCOPES = {
     "series": "tick.series",      # per-tick series capture
 }
 
-#: Refinement passes of the shared-GCS admission gate. The reference
-#: engine's greedy scan admits every *individually* fitting candidate (a
-#: too-big file is skipped, not head-blocking); each prefix-sum pass over
-#: the site-major flattened candidate vector admits the next fitting run
-#: past a blocker. The passes are shared across sites (the per-site
-#: unrolled predecessor gave each site its own three), so a tick with
-#: many oversized blockers can under-admit a later site — bounded and
-#: self-healing: capacity is never exceeded, and a starved candidate is
-#: recomputed as a candidate next tick with fresh passes (a >= 1-tick
-#: migration delay in a pathological tick, inside the statistical
-#: fidelity contract).
-GCS_ADMIT_PASSES = 3
-
 _INF = jnp.float32(jnp.inf)
 _NEG_INF = jnp.float32(-jnp.inf)
 _BIG_TICKET = jnp.int32(2 ** 30)
@@ -153,6 +140,78 @@ def _queue_heads(tickets, W: int):
         idx.append(i)
     idx = jnp.stack(idx, axis=-1)
     return -jnp.take_along_axis(tickets, idx, axis=-1), idx
+
+
+def _vary_like(tree, ref):
+    """Cast ``tree`` to vary over the mesh axes ``ref`` varies over.
+
+    Under ``shard_map`` the lane inputs vary over the mesh axis while
+    freshly made constants do not, and a loop requires its carry's
+    varying axes to match its body's output. The cast is type-level, has
+    no effect on the values, and is a no-op outside ``shard_map``."""
+    vma = jax.typeof(ref).vma
+
+    def cast(x):
+        axes = tuple(sorted(vma - jax.typeof(x).vma))
+        return jax.lax.pcast(x, axes, to="varying") if axes else x
+
+    return jax.tree.map(cast, tree) if vma else tree
+
+
+def _gcs_first_fit(want, sizes, used, limit, gate_pass=None, aux=()):
+    """The shared cloud bucket's admission gate, first fit.
+
+    Scanning the candidates of ``want`` in order (the ``[S, F]`` planes
+    flattened site-major), a candidate is admitted iff the bytes in the
+    bucket (``used``), plus those admitted before it, plus its own size
+    are at most ``limit``: the event engine's greedy scan, where a file
+    too large for the room left waits and a smaller one behind it may
+    still go. Returns ``(admitted, used', passes, aux)``.
+
+    Each pass takes the room ``R = limit - used``, drops the candidates
+    larger than ``R`` (they cannot fit for the rest of the tick: the
+    room only shrinks), and admits the prefix of the rest whose cumsum
+    is at most ``R``. That prefix is what first fit admits up to the
+    next candidate that does not fit, so the passes reproduce first fit
+    exactly. A pass admits at least its first candidate, so the loop
+    ends. An unlimited bucket takes one pass on a tick with candidates,
+    and a tick with none (or a disabled bucket) takes none.
+
+    ``gate_pass(admitted, used, rem, aux) -> (admitted, used, aux)`` runs
+    one pass over ``rem``, the candidates that fit the room; the default
+    is the cumsum below, and the Pallas path (``lane_tick.gcs_admit``)
+    passes its kernel, with ``aux`` its fused storage integration.
+
+    The loop carries the planes in their own shape and each pass works
+    on their flattened views: the compiler then reduces the admitted
+    bytes in the shape it reduced them in before the loop existed (on a
+    TPU the order of a float sum follows the shape it reduces), and an
+    unlimited bucket's level stays bitwise what it was.
+    """
+    def fitting(admitted, used):
+        return want & ~admitted & (sizes <= limit - used)
+
+    if gate_pass is None:
+        sizes_flat = sizes.reshape(-1)
+
+        def gate_pass(admitted, used, rem, aux):
+            rem_flat = rem.reshape(-1)
+            csum = jnp.cumsum(sizes_flat * rem_flat)
+            new = rem_flat & (csum <= limit - used)
+            used = used + jnp.sum(sizes_flat * new)
+            return admitted | new.reshape(rem.shape), used, aux
+
+    def one_pass(carry):
+        admitted, used, rem, passes, aux = carry
+        admitted, used, aux = gate_pass(admitted, used, rem, aux)
+        return admitted, used, fitting(admitted, used), passes + 1, aux
+
+    admitted = jnp.zeros_like(want)
+    init = _vary_like((admitted, used, fitting(admitted, used),
+                       jnp.int32(0), aux), want)
+    admitted, used, _, passes, aux = jax.lax.while_loop(
+        lambda carry: jnp.any(carry[2]), one_pass, init)
+    return admitted, used, passes, aux
 
 
 #: Per-site link-type order of the captured link-activity series (the
@@ -357,36 +416,29 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
             delete = cand & (~gcs_enabled | (gs == PRESENT)
                              | ((gs == ABSENT) & ~(pop >= min_pop)))
             want_mig = cand & migratable
-            # Shared GCS capacity: a prefix-sum admission gate over the
-            # site-major flattened candidate vector (one cumsum covers every
-            # site; earlier candidates' admissions are visible to later ones),
-            # refined over a few passes so a too-big blocker does not head-
-            # block the fitting candidates behind it. The kernel path runs
-            # each pass as one Pallas call over the sequential site grid,
-            # byte totals carried across site blocks and the previous
-            # pass's mask re-entering as an aliased input, fusing the
-            # end-of-tick GB-second integration; its blocked cumsum
-            # reassociates the float totals, so admission matches the jnp
-            # program statistically (capacity-boundary ties), not bitwise.
+            # Shared GCS capacity: first fit over the site-major flattened
+            # candidate vector (``_gcs_first_fit``), so earlier sites' and
+            # files' admissions are visible to later ones and a file too
+            # large for the room left waits on disk for a later tick. The
+            # kernel path runs the same loop with a Pallas call over the
+            # sequential site grid as each pass, fusing the end-of-tick
+            # GB-second integration; its blocked cumsum reassociates the
+            # float totals, so it matches the jnp program statistically
+            # (capacity-boundary ties), not bitwise.
             if use_kernel:
-                mig_f, gcs_used, gbsec_add = lane_tick.gcs_admit(
+                mig, gcs_used, gbsec_add, passes = lane_tick.gcs_admit(
                     want_mig, sizes, st["gcs_used"], gcs_limit, dt,
-                    month_onehot, n_passes=GCS_ADMIT_PASSES,
-                    interpret=interpret)
-                mig = mig_f > 0.5
+                    month_onehot, _gcs_first_fit, interpret=interpret)
             else:
-                want_flat = want_mig.reshape(-1)
-                sizes_flat = sizes.reshape(-1)
-                admitted_flat = jnp.zeros((S * F,), bool)
-                gcs_used = st["gcs_used"]
-                for _ in range(GCS_ADMIT_PASSES):
-                    rem = want_flat & ~admitted_flat
-                    csum = jnp.cumsum(sizes_flat * rem)
-                    new = rem & (gcs_used + csum <= gcs_limit)
-                    gcs_used = gcs_used + jnp.sum(sizes_flat * new)
-                    admitted_flat = admitted_flat | new
-                mig = admitted_flat.reshape(S, F)
+                mig, gcs_used, passes, _ = _gcs_first_fit(
+                    want_mig, sizes, st["gcs_used"], gcs_limit)
+            refused = jnp.any(want_mig & ~mig)
             st["gcs_used"] = gcs_used
+            st["gate_passes"] = st["gate_passes"] + passes
+            st["refused_ticks"] = st["refused_ticks"] + refused.astype(
+                jnp.int32)
+            st["first_refusal"] = jnp.minimum(
+                st["first_refusal"], jnp.where(refused, now, _INF))
             st["gcs_state"] = jnp.where(mig, IN_FLIGHT, gs)
             st["disk_used"] -= jnp.sum(sizes * delete, axis=1)
             st["disk_state"] = jnp.where(delete, ABSENT, st["disk_state"])
@@ -698,6 +750,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
             "cls_a_mo": st["cls_a_mo"],
             "cls_b_mo": st["cls_b_mo"],
             "gbsec_mo": st["gbsec_mo"],
+            "gcs_gate_passes": st["gate_passes"],
+            "gcs_refused_ticks": st["refused_ticks"],
+            "gcs_first_refusal_s": st["first_refusal"],
         }
 
     return tick_fn, post_fn
@@ -754,6 +809,12 @@ def _build_lane_sim(S: int, K: int, n_months: int, impl_name: str,
             cls_a_mo=jnp.zeros((n_months,), jnp.float32),
             cls_b_mo=jnp.zeros((n_months,), jnp.float32),
             gbsec_mo=jnp.zeros((n_months,), jnp.float32),
+            # the cloud admission gate's counters (``_gcs_first_fit``):
+            # passes run, ticks that refused a candidate for lack of room,
+            # and the time of the first such tick (inf: none)
+            gate_passes=jnp.int32(0),
+            refused_ticks=jnp.int32(0),
+            first_refusal=jnp.float32(jnp.inf),
         )
         if record is not None:
             n_samples = record[1]  # +1 = the non-sample-tick trash slot
@@ -764,15 +825,8 @@ def _build_lane_sim(S: int, K: int, n_months: int, impl_name: str,
                 ser_run=jnp.zeros((n_samples + 1, S), jnp.float32),
                 ser_link=jnp.zeros((n_samples + 1, S, 3), jnp.float32),
             )
-        # Under ``shard_map`` the lane inputs vary over the mesh axis while
-        # these constants do not, and scan requires the carry's varying
-        # axes to match the tick's output. Cast the initial carry to vary
-        # over whatever the lane inputs vary over: a type-level cast with
-        # no effect on the values, and a no-op outside ``shard_map``.
-        vma = tuple(sorted(jax.typeof(sizes).vma))
-        if vma:
-            init = jax.tree.map(
-                lambda x: jax.lax.pcast(x, vma, to="varying"), init)
+        # the scan's carry has to vary like the tick's output
+        init = _vary_like(init, sizes)
         final, _ = jax.lax.scan(
             lambda c, xs: tick_fn(c, xs, const), init,
             (times, dts, month_idx, t_idx, jobs_per_tick))
@@ -812,16 +866,22 @@ def _shard_program(S: int, K: int, n_months: int, impl_name: str,
     The lane-axis extent of every lane argument must divide
     ``n_shards``; callers pad by replicating the last lane, exactly as
     the chunked path does. The 5 shared tick-grid arguments are
-    replicated to every device."""
+    replicated to every device.
+
+    The Pallas kernels' outputs state no mesh axes they vary over, and
+    interpret mode's grid loop indexes lane data with unvarying indices,
+    so the kernel program is traced without ``shard_map``'s varying-axes
+    check: a type check only, which changes no value."""
     from repro.parallel.sharding import LANES_AXIS, lane_mesh
 
     lane_sim = _build_lane_sim(S, K, n_months, impl_name, record)
     mesh = lane_mesh(n_shards)
     P = jax.sharding.PartitionSpec
     in_specs = (P(),) * 5 + (P(LANES_AXIS),) * 15
-    sharded = jax.shard_map(jax.vmap(lane_sim, in_axes=_LANE_AXES),
-                            mesh=mesh, in_specs=in_specs,
-                            out_specs=P(LANES_AXIS))
+    sharded = jax.shard_map(
+        jax.vmap(lane_sim, in_axes=_LANE_AXES), mesh=mesh,
+        in_specs=in_specs, out_specs=P(LANES_AXIS),
+        check_vma=not resolve_tick_impl(impl_name).use_kernel)
     return jax.jit(sharded)
 
 
@@ -988,6 +1048,13 @@ def _lane_result(grid: "PackedGrid", out: dict, si: int,
         "class_b": [float(x) for x in out["cls_b_mo"][li]],
         "full_months": int(grid.full_months),
     }
+    first_refusal_s = float(out["gcs_first_refusal_s"][li])
+    counters = {
+        "gcs_gate_passes": int(out["gcs_gate_passes"][li]),
+        "gcs_refused_ticks": int(out["gcs_refused_ticks"][li]),
+        "gcs_first_refusal_h": (first_refusal_s / 3600.0
+                                if np.isfinite(first_refusal_s) else None),
+    }
     return ScenarioResult(
         spec=spec,
         metrics=m,
@@ -997,6 +1064,7 @@ def _lane_result(grid: "PackedGrid", out: dict, si: int,
         wall_s=wall_s,
         events=grid.n_ticks,
         monthly=monthly,
+        counters=counters,
     )
 
 
@@ -1293,6 +1361,10 @@ def run_sweep_jax(specs: Sequence["ScenarioSpec"], tick: float = 10.0,
             help="Dynamics lanes simulated on device")
     reg.observe("sweep.jax.wall_s", wall,
                 help="Batched JAX sweep wall time (s)")
+    reg.inc("sweep.jax.gcs_gate_passes",
+            int(np.sum(out["gcs_gate_passes"])) if out else 0,
+            help="Passes of the cloud admission gate over every lane's "
+                 "ticks")
     capture = _normalize_record(record_series, grid.n_ticks) is not None
     ok_sis = [si for si in range(grid.n_specs)
               if int(grid.lane_of[si]) not in missing]

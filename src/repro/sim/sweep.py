@@ -57,6 +57,12 @@ class ScenarioResult:
     #: cache (``repro.sim.cache``) serves pricing variants of one stored
     #: dynamics lane. Empty for synthetic results that never simulated.
     monthly: Dict[str, Any] = field(default_factory=dict)
+    #: Counters of the device program that simulated this result (the
+    #: batched engine's cloud admission gate: ``gcs_gate_passes``,
+    #: ``gcs_refused_ticks``, ``gcs_first_refusal_h``, the last ``None``
+    #: when no tick refused). Empty for the event engine and for results
+    #: served from the result cache, which ran no device work.
+    counters: Dict[str, Any] = field(default_factory=dict)
 
     @property
     def cost_usd(self) -> float:

@@ -276,10 +276,13 @@ def test_shard_map_bitwise_identical():
 
 
 def test_shard_map_bitwise_identical_on_four_devices():
-    """``shard=True`` over 4 (virtual CPU) devices: the scan carry must
-    vary over the lane axis like the tick's output, and per-lane results
-    must equal the unsharded program's bitwise. Runs in a subprocess so
-    the forced device count does not leak into this test session."""
+    """``shard=True`` over 4 (virtual CPU) devices: the scan carry, the
+    cloud gate's loop carry and the Pallas kernels' outputs must vary
+    over the lane axis like the tick's output, and per-lane results must
+    equal the unsharded program's bitwise, for the jnp program and the
+    kernels in interpret mode, with a lane whose bucket quota binds. Runs
+    in a subprocess so the forced device count does not leak into this
+    test session."""
     import os
     import subprocess
     import sys
@@ -287,20 +290,28 @@ def test_shard_map_bitwise_identical_on_four_devices():
 
     prog = textwrap.dedent("""
         import jax, numpy as np
-        from repro.core.scenarios import expand_grid, pack_specs
+        from repro.core.scenarios import (ScenarioSpec, expand_grid,
+                                          pack_specs)
         from repro.sim.batched import simulate_packed
 
         assert len(jax.devices()) == 4, jax.devices()
         specs = expand_grid({"base": "III", "cache_tb": [10.0, 15.0, 20.0],
                              "seed": 7, "days": 0.05, "n_files": 300})
+        specs.append(ScenarioSpec(base="III", cache_tb=0.5, gcs_limit_tb=0.1,
+                                  seed=7, days=0.05, n_files=300))
         grid = pack_specs(specs, tick=60.0)
-        plain = simulate_packed(grid)
-        for kw in ({}, {"lane_chunk": 2}):
-            sharded = simulate_packed(grid, shard=True, **kw)
-            assert set(sharded) == set(plain)
-            for key in plain:
-                np.testing.assert_array_equal(plain[key], sharded[key],
-                                              err_msg=key)
+        runs = (("jnp", (None, 2)), ("pallas_interpret", (None,)))
+        for impl, chunks in runs:
+            plain = simulate_packed(grid, tick_impl=impl)
+            for chunk in chunks:
+                sharded = simulate_packed(grid, shard=True, lane_chunk=chunk,
+                                          tick_impl=impl)
+                assert set(sharded) == set(plain)
+                for key in plain:
+                    np.testing.assert_array_equal(plain[key], sharded[key],
+                                                  err_msg=impl + " " + key)
+            assert plain["gcs_refused_ticks"][-1] > 0  # the quota binds
+            assert plain["gcs_gate_passes"][-1] > 1
         print("OK")
     """)
     src = os.path.join(os.path.dirname(__file__), "..", "src")

@@ -212,29 +212,36 @@ def test_lane_transfer_tick_matches_oracle():
 
 def test_lane_gcs_admit_matches_global_cumsum_oracle():
     from repro.kernels import lane_tick
+    from repro.sim.batched import _gcs_first_fit
 
     rng = np.random.default_rng(7)
-    S, F, n_passes = 4, 33, 3
+    S, F = 4, 33
     want = rng.random((S, F)) < 0.4
     sizes = rng.uniform(1e6, 1e9, (S, F)).astype(np.float32)
     used0, limit = np.float32(2e9), np.float32(2e10)
     dt, month_onehot = 60.0, np.asarray([0.0, 1.0, 0.0], np.float32)
 
-    # oracle: GCS_ADMIT_PASSES passes of a global cumsum over the
-    # site-major flattened candidate vector (the jnp program's loop)
+    # oracle: the first-fit passes of a global cumsum over the site-major
+    # flattened candidate vector (the jnp program's ``_gcs_first_fit``)
     admitted = np.zeros((S, F), bool)
     used = used0
-    for _ in range(n_passes):
-        rem = want & ~admitted
+    passes = 0
+    while True:
+        rem = want & ~admitted & (sizes <= limit - used)
+        if not rem.any():
+            break
         csum = np.cumsum((sizes * rem).ravel()).reshape(S, F)
-        new = rem & (used + csum <= limit)
+        new = rem & (csum <= limit - used)
         admitted |= new
         used = used + (sizes * new).sum(dtype=np.float64).astype(np.float32)
+        passes += 1
+    assert passes > 1 and (want & ~admitted).any()  # the quota binds
 
-    adm, used_k, gbsec = lane_tick.gcs_admit(
+    adm, used_k, gbsec, passes_k = lane_tick.gcs_admit(
         jnp.asarray(want), jnp.asarray(sizes), used0, limit, dt,
-        jnp.asarray(month_onehot), n_passes=n_passes, interpret=True)
-    np.testing.assert_array_equal(np.asarray(adm) > 0.5, admitted)
+        jnp.asarray(month_onehot), _gcs_first_fit, interpret=True)
+    np.testing.assert_array_equal(np.asarray(adm), admitted)
+    assert int(passes_k) == passes
     np.testing.assert_allclose(float(used_k), used, rtol=1e-5)
     np.testing.assert_allclose(
         np.asarray(gbsec), month_onehot * (used / 1e9 * dt), rtol=1e-5)

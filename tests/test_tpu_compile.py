@@ -146,6 +146,22 @@ def test_grid_program_selects_queue_heads_without_a_sort(
     assert re.search(r'op_name="[^"]*?/tick\.waitq[/"]', hlo)
 
 
+def test_v5e_grid_program_keeps_one_plane_wide_cumsum(topo, f20k_grid,
+                                                      no_compile_cache):
+    """On the TPU a cumsum lowers to ``reduce-window``s. The migrations'
+    ranks are a blocked count on the MXU, so of the cfg III program's
+    plane-wide ones (at least lanes x S x F elements) only the GCS gate's
+    is left: it runs over the site-major flattened ``[L, S·F]`` vector,
+    which no ``[L, S, ...]`` shape of a per-site count has."""
+    hlo = _f20k_hlo(f20k_grid, topo.devices[0])
+    L, S, F = f20k_grid.sizes.shape
+    shapes = [[int(d) for d in dims.split(",")] for dims in re.findall(
+        r"= \w+\[([\d,]+)\]\{[^}]*\} reduce-window\(", hlo)]
+    wide = [dims for dims in shapes if np.prod(dims) >= L * S * F]
+    assert len(wide) == 1 and wide[0][:2] != [L, S], wide
+    assert re.search(r'op_name="[^"]*?/tick\.migrate/dot_general"', hlo)
+
+
 def test_shard_program_compiles_over_four_v5e_without_collectives(
         topo, paper_grid, monkeypatch, no_compile_cache):
     from repro.parallel import sharding

@@ -349,25 +349,6 @@ def run(n_configs: int = 8, days: float = 0.25, n_files: int = 4000,
          "derived": warm_cps / base_cps if base_cps > 0 else 0.0},
     ]
 
-    # -- fused Pallas tick kernels (ISSUE 7), interpret mode on CPU --
-    # Plumbing/overhead measurement, not a speed claim (see
-    # bench_tick_engine.py's row-naming note): tick.pallas.* rows must
-    # stay out of the bench-smoke regression gate's default rows.
-    run_sweep(jspecs, backend="jax", tick=JAX_BENCH_TICK,
-              tick_impl="pallas_interpret")  # absorb the compile
-    pallas_warm = run_sweep(jspecs, backend="jax", tick=JAX_BENCH_TICK,
-                            tick_impl="pallas_interpret")
-    rows += [
-        {"name": f"tick.pallas.sweep_warm.{g}cfg{n_lanes}lane",
-         "us_per_call": pallas_warm.wall_s / g * 1e6,
-         "derived": _cps(pallas_warm)},
-        # derived = interpret-mode wall / jnp wall on the identical warm
-        # grid (values > 1 mean the interpreter overhead, expected on CPU)
-        {"name": "tick.pallas.sweep_vs_jnp",
-         "us_per_call": pallas_warm.wall_s * 1e6,
-         "derived": pallas_warm.wall_s / warm.wall_s
-         if warm.wall_s > 0 else 0.0},
-    ]
     rows += _obs_overhead_rows(jspecs)
     rows += _resilience_overhead_rows(jspecs)
     rows += _lane_scaling_rows(0.1, jfiles,

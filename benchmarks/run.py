@@ -6,7 +6,6 @@ columns where the paper provides reference values).
   table2   bench_validation   (simulation correctness, 5 metrics)
   table6/7 bench_hcdc         (jobs done, volumes for cfg I/II/III)
   table8   bench_cost         (monthly GCS cost, cfg III)
-  hotloop  bench_tick_engine  (transfer-manager tick engines)
   sweep    bench_sweep        (scenario-sweep engine: process configs/sec
                                + batched-backend lanes/sec)
   fleet    bench_fleet        (worker-fleet lane scaling: 1024/10k-lane
@@ -96,16 +95,6 @@ def main() -> int:
         return rows
 
     section("cost", cost)
-
-    def tick_engine():
-        from benchmarks import bench_tick_engine
-        rows = bench_tick_engine.run()
-        for r in rows:
-            print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']:.4g}",
-                  flush=True)
-        return rows
-
-    section("tick_engine", tick_engine)
 
     def sweep():
         from benchmarks import bench_sweep

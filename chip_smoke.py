@@ -122,14 +122,12 @@ def timed_sweep(specs, clock, **kw):
 
 def describe_grid(specs) -> None:
     from repro.core.scenarios import pack_specs
-    from repro.kernels.registry import resolve_tick_impl
 
     grid = pack_specs(specs, tick=TICK)
     say(f"grid: configs={grid.n_specs} L={grid.n_lanes} "
         f"S={len(grid.site_names)} F={grid.sizes.shape[2]} "
         f"ticks={grid.n_ticks} K={grid.max_jobs_per_tick} "
-        f"J={grid.job_fid.shape[2]} tick={TICK:g}s days={specs[0].days:g} "
-        f"tick_impl={resolve_tick_impl('auto').name}")
+        f"J={grid.job_fid.shape[2]} tick={TICK:g}s days={specs[0].days:g}")
 
 
 def check_results(res) -> None:

@@ -51,13 +51,6 @@ EXIT_NO_CURRENT = 5  # the fresh results file itself is absent/unreadable
 #: Rows that gate CI (prefix match). Throughput of the batched backend is
 #: the perf trajectory this repo tracks (ISSUE 4 acceptance); the decide
 #: rows track the decision layer's lane efficiency (ISSUE 5).
-#:
-#: Never add ``tick.pallas.*`` rows here: on this CPU container those are
-#: interpret-mode artifacts (Pallas traced through XLA — a plumbing and
-#: parity path, ISSUE 7), so their "throughput" measures interpreter
-#: overhead, not kernel speed. Gating bench-smoke on one would fail PRs
-#: over noise in a number nobody optimizes. The nightly table-only run
-#: may still *report* them (``--baseline -``).
 DEFAULT_ROWS = ("sweep.jax.warm", "sweep.jax.lanes_per_sec")
 
 

@@ -29,7 +29,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core.scenarios import EGRESS_OPTIONS, ScenarioSpec
-from repro.kernels.registry import TICK_IMPL_CHOICES
 from repro.obs.logs import LOG_LEVELS, setup_logging
 from repro.obs.metrics import get_registry
 from repro.obs.trace import get_tracer
@@ -144,13 +143,7 @@ def main(argv=None) -> int:
     ap.add_argument("--backend", default="jax",
                     choices=["jax", "process"])
     ap.add_argument("--tick", type=float, default=60.0,
-                    help="jax-backend clock step, seconds (default 60); "
-                         "distinct from --tick-impl (kernel choice)")
-    ap.add_argument("--tick-impl", default="auto",
-                    choices=TICK_IMPL_CHOICES,
-                    help="jax-backend kernel implementation (auto = "
-                         "jnp on every platform; "
-                         "see docs/simulation.md, 'Kernel selection')")
+                    help="jax-backend clock step, seconds (default 60)")
     ap.add_argument("--lane-chunk", type=int, default=None)
     ap.add_argument("--workers", type=int, default=None)
     ap.add_argument("--transport", default=None,
@@ -200,9 +193,6 @@ def main(argv=None) -> int:
         log.error("%s", e)
         return 2
 
-    if args.tick_impl != "auto" and args.backend != "jax":
-        log.error("--tick-impl requires --backend jax")
-        return 2
     if args.shard and args.backend != "jax":
         log.error("--shard requires --backend jax")
         return 2
@@ -223,7 +213,7 @@ def main(argv=None) -> int:
         use_compile_cache()
     try:
         driver = SweepDriver(backend=args.backend, tick=args.tick,
-                             workers=args.workers, tick_impl=args.tick_impl,
+                             workers=args.workers,
                              lane_chunk=args.lane_chunk, cache=cache_dir,
                              retry=retry, faults=args.faults,
                              job_timeout=args.job_timeout,
@@ -322,7 +312,6 @@ def main(argv=None) -> int:
         # engine-fingerprinted, so the other backend's entries never
         # collide with this run's) — a warm nightly re-check is free.
         ref = run_sweep(specs, backend=other, tick=args.tick,
-                        tick_impl=args.tick_impl if other == "jax" else "auto",
                         workers=args.workers, cache=cache_dir)
         mine = driver.run(specs)  # cached — no new simulation
         bad = []

@@ -42,7 +42,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core.scenarios import EGRESS_OPTIONS, specs_from_mapping
-from repro.kernels.registry import TICK_IMPL_CHOICES
 from repro.obs.logs import LOG_LEVELS, setup_logging
 from repro.obs.metrics import get_registry
 from repro.obs.trace import get_tracer, jax_device_profile
@@ -146,17 +145,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tick", type=float, default=10.0,
                     help="jax backend clock step in seconds (default 10, "
                          "the paper's generator interval; larger ticks "
-                         "trade temporal resolution for speed). Distinct "
-                         "from --tick-impl, which picks the kernel")
-    ap.add_argument("--tick-impl", default="auto",
-                    choices=TICK_IMPL_CHOICES,
-                    help="jax backend kernel implementation: jnp (the "
-                         "oracle program), pallas (compiled kernels; "
-                         "accelerator), pallas_interpret (kernels traced "
-                         "through the Pallas interpreter — parity/CI "
-                         "path, not a speed mode), or auto (default: "
-                         "jnp on every platform). See "
-                         "docs/simulation.md, 'Kernel selection'")
+                         "trade temporal resolution for speed)")
     ap.add_argument("--lane-chunk", type=int, default=None, metavar="N",
                     help="jax backend: simulate at most N dynamics lanes "
                          "per device dispatch (bounded memory for large "
@@ -266,9 +255,6 @@ def main(argv=None) -> int:
     if args.lane_chunk is not None and args.backend != "jax":
         log.error("--lane-chunk requires --backend jax")
         return 2
-    if args.tick_impl != "auto" and args.backend != "jax":
-        log.error("--tick-impl requires --backend jax")
-        return 2
     if args.record_series is not None and args.backend != "jax":
         log.error("--record-series requires --backend jax "
                   "(use --curves for the process backend)")
@@ -280,8 +266,8 @@ def main(argv=None) -> int:
         use_compile_cache()
         chunk = ("" if args.lane_chunk is None
                  else f", lane_chunk={args.lane_chunk}")
-        log.info("sweep: %d configs, backend=jax (tick=%gs, tick_impl=%s%s)",
-                 len(specs), args.tick, args.tick_impl, chunk)
+        log.info("sweep: %d configs, backend=jax (tick=%gs%s)",
+                 len(specs), args.tick, chunk)
     else:
         workers = (min(len(specs), os.cpu_count() or 1)
                    if args.workers is None else args.workers)
@@ -316,7 +302,6 @@ def main(argv=None) -> int:
             result = run_sweep(specs, workers=args.workers,
                                progress=progress,
                                backend=args.backend, tick=args.tick,
-                               tick_impl=args.tick_impl,
                                lane_chunk=args.lane_chunk, cache=cache_dir,
                                record_series=args.record_series,
                                retry=retry, faults=args.faults,

@@ -250,8 +250,7 @@ RESULT_SCHEMA_VERSION = 2
 
 
 def engine_fingerprint(backend: str = "process",
-                       tick: Optional[float] = None,
-                       tick_impl: Optional[str] = None) -> str:
+                       tick: Optional[float] = None) -> str:
     """Canonical engine identity for result caching.
 
     The event-driven reference engine is bit-deterministic per spec, so
@@ -261,47 +260,18 @@ def engine_fingerprint(backend: str = "process",
     chunked execution is bitwise identical to the unchunked run. The two
     engines agree statistically, not bitwise, so their entries never
     substitute for each other.
-
-    ``tick_impl`` (jax backend only) is the *resolved* kernel
-    implementation (``repro.kernels.registry``): ``"jnp"`` (or ``None``)
-    keeps the legacy ``jax:<tick>`` fingerprint — the jnp program *is*
-    the pre-registry engine bit-for-bit, so its entries stay shared —
-    while the Pallas implementations append their name
-    (``"jax:60:pallas"``), because kernel results match the jnp oracle
-    statistically (blocked-cumsum admission ties, fused-multiply-add
-    rounding), not bitwise, and must never cross-serve. ``"auto"`` is
-    rejected here: resolve it per host *before* keying
-    (``resolve_tick_impl``), otherwise one key could name two different
-    programs on two machines.
-
-    Legacy-store caveat: entries written *before* this axis existed by a
-    TPU host carry the bare ``jax:<tick>`` key but came from the old
-    auto-selected interpret-mode kernel (~1 ulp off the jnp program), so
-    they would cross-serve ``"jnp"`` requests within tolerance but not
-    bitwise. No known store was written on an accelerator host; if one
-    exists, drop its ``jax:*`` entries or bump
-    :data:`RESULT_SCHEMA_VERSION` instead of sharing the key.
     """
     if backend == "process":
         return "process"
     if backend == "jax":
         t = 10.0 if tick is None else float(tick)
-        impl = "jnp" if tick_impl is None else str(tick_impl)
-        if impl == "jnp":
-            return f"jax:{t:g}"
-        if impl in ("pallas", "pallas_interpret"):
-            return f"jax:{t:g}:{impl}"
-        raise ValueError(
-            f"tick_impl {tick_impl!r} cannot be fingerprinted (expected "
-            "a resolved implementation: 'jnp', 'pallas' or "
-            "'pallas_interpret'; resolve 'auto' first)")
+        return f"jax:{t:g}"
     raise ValueError(f"unknown backend {backend!r} "
                      "(expected 'process' or 'jax')")
 
 
 def cache_key(spec: ScenarioSpec, backend: str = "process",
-              tick: Optional[float] = None,
-              tick_impl: Optional[str] = None) -> str:
+              tick: Optional[float] = None) -> str:
     """Content address of a spec's *dynamics* result (sha256 hex digest).
 
     The key hashes the canonical JSON of ``(schema version, engine
@@ -314,7 +284,7 @@ def cache_key(spec: ScenarioSpec, backend: str = "process",
     """
     doc = {
         "schema": RESULT_SCHEMA_VERSION,
-        "engine": engine_fingerprint(backend, tick, tick_impl),
+        "engine": engine_fingerprint(backend, tick),
         "spec": asdict(dynamics_key(spec)),
     }
     blob = json.dumps(doc, sort_keys=True, separators=(",", ":"))

@@ -1,9 +1,8 @@
 """Labeled metrics registry with process-safe snapshots (ISSUE 8).
 
 The decision workflow's operational story — "is the cache warm?", "how
-many lanes did this run simulate?", "which kernel did ``auto``
-resolve?" — was scattered across ad-hoc attributes (``CacheStats``,
-``SweepDriver`` counters, nothing at all for ``tick_impl``). This module
+many lanes did this run simulate?" — was scattered across ad-hoc
+attributes (``CacheStats``, ``SweepDriver`` counters). This module
 is the one sink: a registry of labeled Counters, Gauges, and Histograms
 that every layer increments, exported as Prometheus text exposition
 format (``to_prometheus``) or JSON (``snapshot``/``to_json_dict``).
@@ -16,9 +15,9 @@ parent folds it in with ``merge`` — counters and histograms add, gauges
 last-write-wins — so a parallel sweep's metrics match a serial run's
 (``docs/observability.md``, "Process model").
 
-The registry is jax-free at import time (stdlib only): it is imported
-from ``repro.kernels.registry``, whose concrete-name resolution must
-never touch jax.
+The registry is jax-free at import time (stdlib only), so jax-free
+flows (the process backend, cache keying) can count without importing
+jax.
 
 Performance: a disabled registry (``enabled = False``) turns every
 ``inc``/``set``/``observe`` into an early-out attribute check, and the

@@ -5,15 +5,8 @@ per Python interpreter; the §5.3 decision workflow wants *grids* of
 scenarios. This module runs an entire packed grid as **one** ``jit`` +
 ``vmap`` JAX program: lane ``l`` is one ``ScenarioSpec``, every lane steps
 a shared fixed-tick clock, and per-lane transfer/link state advances
-through the carousel tick math — either the scatter-free one-hot jnp
-formulation (``tick_impl="jnp"``, the numerical oracle and CPU fast
-path) or the fused lane-blocked Pallas kernels
-(``repro.kernels.lane_tick``; ``tick_impl="pallas"`` compiled on an
-accelerator, ``"pallas_interpret"`` as the CI-runnable parity path).
-The implementation axis is the ``tick_impl`` registry
-(``repro.kernels.registry``; ``"auto"`` resolves to jnp) threaded
-down from ``run_sweep``/``SweepDriver``. The paper's billing
-quantities — GCS
+through the carousel tick math in a scatter-free one-hot ``jax.numpy``
+formulation (``_advance_transfers``). The paper's billing quantities — GCS
 byte-seconds, tiered egress volume, class A/B operation counts — are
 accumulated on device per 30-day month bucket and folded into the
 existing ``GCSCostModel`` / ``MonthlyBill`` machinery on the way out, so
@@ -77,8 +70,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import lane_tick
-from repro.kernels.registry import TickImpl, resolve_tick_impl
 from repro.obs.metrics import get_registry
 from repro.obs.trace import get_tracer
 from repro.sim.cloud import bills_from_monthly_totals
@@ -215,7 +206,7 @@ def _vary_like(tree, ref):
     return jax.tree.map(cast, tree) if vma else tree
 
 
-def _gcs_first_fit(want, sizes, used, limit, gate_pass=None, aux=()):
+def _gcs_first_fit(want, sizes, used, limit):
     """The shared cloud bucket's admission gate, first fit.
 
     Scanning the candidates of ``want`` in order (the ``[S, F]`` planes
@@ -223,7 +214,7 @@ def _gcs_first_fit(want, sizes, used, limit, gate_pass=None, aux=()):
     bucket (``used``), plus those admitted before it, plus its own size
     are at most ``limit``: the event engine's greedy scan, where a file
     too large for the room left waits and a smaller one behind it may
-    still go. Returns ``(admitted, used', passes, aux)``.
+    still go. Returns ``(admitted, used', passes)``.
 
     Each pass takes the room ``R = limit - used``, drops the candidates
     larger than ``R`` (they cannot fit for the rest of the tick: the
@@ -234,41 +225,103 @@ def _gcs_first_fit(want, sizes, used, limit, gate_pass=None, aux=()):
     ends. An unlimited bucket takes one pass on a tick with candidates,
     and a tick with none (or a disabled bucket) takes none.
 
-    ``gate_pass(admitted, used, rem, aux) -> (admitted, used, aux)`` runs
-    one pass over ``rem``, the candidates that fit the room; the default
-    is the cumsum below, and the Pallas path (``lane_tick.gcs_admit``)
-    passes its kernel, with ``aux`` its fused storage integration.
-
     The loop carries the planes in their own shape and each pass works
     on their flattened views: the compiler then reduces the admitted
     bytes in the shape it reduced them in before the loop existed (on a
     TPU the order of a float sum follows the shape it reduces), and an
     unlimited bucket's level stays bitwise what it was.
     """
+    sizes_flat = sizes.reshape(-1)
+
     def fitting(admitted, used):
         return want & ~admitted & (sizes <= limit - used)
 
-    if gate_pass is None:
-        sizes_flat = sizes.reshape(-1)
-
-        def gate_pass(admitted, used, rem, aux):
-            rem_flat = rem.reshape(-1)
-            csum = jnp.cumsum(sizes_flat * rem_flat)
-            new = rem_flat & (csum <= limit - used)
-            used = used + jnp.sum(sizes_flat * new)
-            return admitted | new.reshape(rem.shape), used, aux
-
     def one_pass(carry):
-        admitted, used, rem, passes, aux = carry
-        admitted, used, aux = gate_pass(admitted, used, rem, aux)
-        return admitted, used, fitting(admitted, used), passes + 1, aux
+        admitted, used, rem, passes = carry
+        rem_flat = rem.reshape(-1)
+        csum = jnp.cumsum(sizes_flat * rem_flat)
+        new = rem_flat & (csum <= limit - used)
+        used = used + jnp.sum(sizes_flat * new)
+        admitted = admitted | new.reshape(rem.shape)
+        return admitted, used, fitting(admitted, used), passes + 1
 
     admitted = jnp.zeros_like(want)
     init = _vary_like((admitted, used, fitting(admitted, used),
-                       jnp.int32(0), aux), want)
-    admitted, used, _, passes, aux = jax.lax.while_loop(
+                       jnp.int32(0)), want)
+    admitted, used, _, passes = jax.lax.while_loop(
         lambda carry: jnp.any(carry[2]), one_pass, init)
-    return admitted, used, passes, aux
+    return admitted, used, passes
+
+
+def _per_link(table, ltype):
+    """``table[link]`` for every file row of ``[S, F]``, where ``table``
+    is indexed by link id ``3 * site + type`` and ``ltype`` is each row's
+    link type: a select over the type instead of a gather. A file uses
+    only its own site's links. In XLA's cost model for a TPU v5e, five
+    such [S, F] gathers were about 94% of the paper-width tick's memory
+    traffic. Rows with no transfer yet hold link 0 and read their own
+    site's type-0 entry; every use masks those rows."""
+    t3 = table.reshape(ltype.shape[0], 3)
+    return jnp.where(ltype == 0, t3[:, 0:1],
+                     jnp.where(ltype == 1, t3[:, 1:2], t3[:, 2:3]))
+
+
+def _advance_transfers(active, ltype, loc_onehot, done, total, bw, mode, dt):
+    """Advance every transfer in ``active`` by one tick of ``dt`` seconds
+    (the carousel tick math) over the ``[S, F]`` file planes.
+
+    A file only ever transfers on its own site's three links (link id
+    ``3 * site + type``; ``ltype`` is the type, ``loc_onehot`` its
+    one-hot ``[S, F, 3]``), so the per-link active counts are a one-hot
+    reduction over the type axis with no scatter (XLA:CPU expands
+    scatters into O(S·F)-trip sequential loops). A link in dedicated
+    mode (``mode > 0``) gives every transfer its full bandwidth ``bw``;
+    a shared link splits ``bw`` evenly among its active transfers.
+
+    Returns ``(new_done, comp, by_type)``: the bytes done per file,
+    capped at ``total``; the completion mask; and that mask split by
+    link type (tape->disk, gcs->disk, disk->gcs). The tick bills each
+    type's completed bytes, ``sum(sizes * by_type[k], axis=1)``, in
+    between its own state updates.
+    """
+    act_f = active.astype(jnp.float32)
+    counts = jnp.sum(act_f[:, :, None] * loc_onehot,
+                     axis=1).reshape(-1)  # [M], M = 3*S
+    bw_i = _per_link(bw, ltype)
+    shared = bw_i / jnp.maximum(_per_link(counts, ltype), 1.0)
+    rate = jnp.where(_per_link(mode, ltype) > 0, bw_i, shared)
+    new_done = jnp.minimum(total, done + act_f * rate * dt)
+    comp = (new_done >= total) & active
+    return new_done, comp, tuple(comp & (ltype == k) for k in range(3))
+
+
+def _window_admit(live, size, used, limit, fifo, stale=None):
+    """Admit a per-site candidate window against the disk limit, in
+    window order: ``(admitted [S, C], extra [S])``.
+
+    Candidate ``k`` of a site is admitted iff it is ``live`` (and not
+    ``stale``, where given) and the site's disk (``used``), plus the
+    sizes admitted before it in the window, plus its own ``size`` stays
+    within ``limit``; ``extra`` is the bytes admitted. The window is
+    tiny, so the recurrence runs as C steps over ``[S]`` vectors, all
+    sites together. ``fifo=False`` is the K-window of this tick's job
+    arrivals: a candidate that does not fit is passed over. ``fifo=True``
+    is the waiting queue's strict FIFO (§5.2): the first live candidate
+    that does not fit blocks the rest, and a stale one is passed over.
+    """
+    cols = []
+    extra = jnp.zeros_like(used)
+    blocked = jnp.zeros(used.shape, bool) if fifo else None
+    for k in range(live.shape[1]):
+        fit = used + extra + size[:, k] <= limit
+        live_k = live[:, k] if stale is None else live[:, k] & ~stale[:, k]
+        adm = live_k & fit
+        if fifo:
+            adm = adm & ~blocked
+            blocked = blocked | (live_k & ~fit)
+        cols.append(adm)
+        extra = extra + jnp.where(adm, size[:, k], 0.0)
+    return jnp.stack(cols, axis=1), extra
 
 
 #: Per-site link-type order of the captured link-activity series (the
@@ -288,10 +341,9 @@ def _normalize_record(record_series, n_ticks: int):
     return stride, (n_ticks - 1) // stride + 1
 
 
-def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
-                   record=None):
+def _lane_step_fns(S: int, K: int, n_months: int, record=None):
     """Build the per-lane tick body and post-scan reduction (closures over
-    the static dimensions and the resolved tick implementation).
+    the static dimensions).
 
     Vectorization notes: the per-tick candidate sets (this tick's job
     arrivals, the waiting-queue window) are tiny, so their sequential
@@ -317,12 +369,6 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
     exactly the condition the previous per-tick segment-sum over the whole
     [S, J] job table computed, at a fraction of the cost.
 
-    When ``impl.use_kernel`` the transfer advance (+ its completion
-    billing), the shared-GCS admission scan (+ the GB-second storage
-    integration) and the K/W candidate-window recurrences run as the
-    fused ``repro.kernels.lane_tick`` Pallas kernels; the surrounding
-    scatter/bookkeeping program is shared between implementations.
-
     ``record`` (``(stride, n_samples)`` or ``None``) turns on per-tick
     series capture: ring buffers sized ``[n_samples + 1, ...]`` ride in
     the scan carry and every tick writes its end-of-tick observables —
@@ -333,12 +379,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
     lane. With ``record=None`` the carry, the traced program, and the
     results are byte-for-byte the pre-capture ones.
 
-    Each phase of the tick, on both implementations, runs under its
-    ``TICK_SCOPES`` name; the scopes are metadata and leave the
-    program's operations unchanged.
+    Each phase of the tick runs under its ``TICK_SCOPES`` name; the
+    scopes are metadata and leave the program's operations unchanged.
     """
-    use_kernel = impl.use_kernel
-    interpret = impl.interpret
 
     def tick_fn(state, xs, const):
         now, dt, month, t, jobs_now = xs
@@ -356,72 +399,28 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
             # are excluded — their scatters land at the end of the tick).
             no_cons = (st["pend_cnt"] == 0) & (st["fin_max"] <= now)
 
-            # -- advance transfers one tick (the carousel tick math). A file
-            # only ever transfers on its own site's three links (link id =
-            # 3*site + type), so the per-link active counts are a one-hot
-            # reduction over the link-type axis with no scatter (XLA:CPU
-            # expands scatters into O(S·F)-trip sequential loops that
-            # dominated the tick before this formulation). The kernel path
-            # fuses the same math with the completion billing below in one
-            # per-site Pallas block (``lane_tick.transfer_tick``).
+            # -- advance transfers one tick (the carousel tick math)
             now_prev = now - dt
             t_active = st["tr_slot"] & (st["tr_start"] <= now_prev + 0.5)
             ltype = st["tr_link"] % 3  # 0 tape->disk, 1 gcs->disk, 2 disk->gcs
             loc_onehot = ltype[:, :, None] == jnp.arange(3, dtype=jnp.int32)
-
-            def per_link(table):
-                """``table[tr_link]`` for every file row, as a select over the
-                row's link type instead of a gather. A file uses only its own
-                site's links. In XLA's cost model for a TPU v5e, five such
-                [S, F] gathers were about 94% of the paper-width tick's
-                memory traffic. Rows with no transfer yet hold link 0 and read
-                their own site's type-0 entry; every use masks those rows."""
-                t3 = table.reshape(S, 3)
-                return jnp.where(ltype == 0, t3[:, 0:1],
-                                 jnp.where(ltype == 1, t3[:, 1:2], t3[:, 2:3]))
-
-            if use_kernel:
-                month_onehot = (jnp.arange(n_months, dtype=jnp.int32)
-                                == month).astype(jnp.float32)
-                (new_done, comp_f, tape_add, recall_add, mig_add,
-                 egress_add, cls_a_add, cls_b_add) = lane_tick.transfer_tick(
-                    st["tr_link"], t_active, st["tr_done"], st["tr_total"],
-                    sizes, bw, mode, dt, month_onehot, interpret=interpret)
-                comp = comp_f > 0.5
-            else:
-                act_f = t_active.astype(jnp.float32)
-                counts = jnp.sum(act_f[:, :, None] * loc_onehot,
-                                 axis=1).reshape(-1)  # [M], M = 3*S
-                bw_i = per_link(bw)
-                shared = bw_i / jnp.maximum(per_link(counts), 1.0)
-                rate = jnp.where(per_link(mode) > 0, bw_i, shared)
-                new_done = jnp.minimum(st["tr_total"],
-                                       st["tr_done"] + act_f * rate * dt)
-                comp = (new_done >= st["tr_total"]) & t_active
-            comp_tape = comp & (ltype == 0)
-            comp_recall = comp & (ltype == 1)
-            comp_mig = comp & (ltype == 2)
+            new_done, comp, by_type = _advance_transfers(
+                t_active, ltype, loc_onehot, st["tr_done"], st["tr_total"],
+                bw, mode, dt)
+            comp_tape, comp_recall, comp_mig = by_type
             inbound = comp_tape | comp_recall
 
             st["disk_state"] = jnp.where(inbound, PRESENT, st["disk_state"])
             st["gcs_state"] = jnp.where(comp_mig, PRESENT, st["gcs_state"])
-            if use_kernel:  # billing deltas came fused out of the kernel
-                st["tape_b"] += tape_add
-                st["gcsdisk_b"] += recall_add
-                st["diskgcs_b"] += mig_add
-                st["egress_mo"] += egress_add
-                st["cls_a_mo"] += cls_a_add
-                st["cls_b_mo"] += cls_b_add
-            else:
-                st["tape_b"] += jnp.sum(sizes * comp_tape, axis=1)
-                st["gcsdisk_b"] += jnp.sum(sizes * comp_recall, axis=1)
-                recall_bytes = jnp.sum(sizes * comp_recall)
-                st["egress_mo"] = st["egress_mo"].at[month].add(recall_bytes)
-                st["cls_b_mo"] = st["cls_b_mo"].at[month].add(
-                    jnp.sum(comp_recall).astype(jnp.float32))
-                st["diskgcs_b"] += jnp.sum(sizes * comp_mig, axis=1)
-                st["cls_a_mo"] = st["cls_a_mo"].at[month].add(
-                    jnp.sum(comp_mig).astype(jnp.float32))
+            st["tape_b"] += jnp.sum(sizes * comp_tape, axis=1)
+            st["gcsdisk_b"] += jnp.sum(sizes * comp_recall, axis=1)
+            recall_bytes = jnp.sum(sizes * comp_recall)
+            st["egress_mo"] = st["egress_mo"].at[month].add(recall_bytes)
+            st["cls_b_mo"] = st["cls_b_mo"].at[month].add(
+                jnp.sum(comp_recall).astype(jnp.float32))
+            st["diskgcs_b"] += jnp.sum(sizes * comp_mig, axis=1)
+            st["cls_a_mo"] = st["cls_a_mo"].at[month].add(
+                jnp.sum(comp_mig).astype(jnp.float32))
             # migrated with no remaining consumer: drop the hot copy now
             drop_hot = comp_mig & no_cons & (st["disk_state"] == PRESENT)
             st["disk_used"] -= jnp.sum(sizes * drop_hot, axis=1)
@@ -452,10 +451,10 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
             admit = jnp.minimum(free, n_q).astype(jnp.int32)
             new_serve = st["lq_serve"] + admit
             adm_row = st["lq_queued"] & \
-                (st["lq_ticket"] < per_link(new_serve))
+                (st["lq_ticket"] < _per_link(new_serve, ltype))
             st["tr_slot"] = st["tr_slot"] | adm_row
-            st["tr_start"] = jnp.where(adm_row, now + per_link(latency),
-                                       st["tr_start"])
+            st["tr_start"] = jnp.where(
+                adm_row, now + _per_link(latency, ltype), st["tr_start"])
             st["lq_queued"] = st["lq_queued"] & ~adm_row
             st["lq_serve"] = new_serve
             occ3 = (occ + admit.astype(jnp.float32)).reshape(S, 3)
@@ -476,19 +475,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
             # Shared GCS capacity: first fit over the site-major flattened
             # candidate vector (``_gcs_first_fit``), so earlier sites' and
             # files' admissions are visible to later ones and a file too
-            # large for the room left waits on disk for a later tick. The
-            # kernel path runs the same loop with a Pallas call over the
-            # sequential site grid as each pass, fusing the end-of-tick
-            # GB-second integration; its blocked cumsum reassociates the
-            # float totals, so it matches the jnp program statistically
-            # (capacity-boundary ties), not bitwise.
-            if use_kernel:
-                mig, gcs_used, gbsec_add, passes = lane_tick.gcs_admit(
-                    want_mig, sizes, st["gcs_used"], gcs_limit, dt,
-                    month_onehot, _gcs_first_fit, interpret=interpret)
-            else:
-                mig, gcs_used, passes, _ = _gcs_first_fit(
-                    want_mig, sizes, st["gcs_used"], gcs_limit)
+            # large for the room left waits on disk for a later tick.
+            mig, gcs_used, passes = _gcs_first_fit(
+                want_mig, sizes, st["gcs_used"], gcs_limit)
             refused = jnp.any(want_mig & ~mig)
             st["gcs_used"] = gcs_used
             st["gate_passes"] = st["gate_passes"] + passes
@@ -597,21 +586,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
                 ww = jnp.take_along_axis(st["wq_wait"], fids, axis=1)
                 tailw = jnp.take_along_axis(job_tail, jid, axis=1)
                 absent = first & (ds == ABSENT)
-                if use_kernel:
-                    started_f, extra = lane_tick.window_admit(
-                        absent, size, st["disk_used"], disk_limit,
-                        fifo=False, interpret=interpret)
-                    started = started_f > 0.5
-                else:
-                    started_cols = []
-                    extra = jnp.zeros((S,), jnp.float32)
-                    for k in range(K):  # prefix recurrence over the window;
-                        fit = st["disk_used"] + extra + size[:, k] \
-                            <= disk_limit   # all sites advance together
-                        st_k = absent[:, k] & fit
-                        started_cols.append(st_k)
-                        extra = extra + jnp.where(st_k, size[:, k], 0.0)
-                    started = jnp.stack(started_cols, axis=1)  # [S, K]
+                started, extra = _window_admit(absent, size,
+                                               st["disk_used"], disk_limit,
+                                               fifo=False)
                 st["disk_used"] = st["disk_used"] + extra
                 to_wait = absent & ~started & ~ww
                 wrank = jnp.cumsum(to_wait.astype(jnp.int32), axis=1) - 1
@@ -650,23 +627,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
             ds = jnp.take_along_axis(st["disk_state"], idx, axis=1)
             stale = validw & ((ds != ABSENT) | jumped)
             size = jnp.take_along_axis(sizes, idx, axis=1)
-            if use_kernel:
-                admitted_f, extra = lane_tick.window_admit(
-                    validw & ~stale, size, st["disk_used"], disk_limit,
-                    fifo=True, interpret=interpret)
-                admitted = admitted_f > 0.5
-            else:
-                adm_cols = []
-                extra = jnp.zeros((S,), jnp.float32)
-                blocked = jnp.zeros((S,), bool)
-                for k in range(W):  # FIFO prefix recurrence, sites together
-                    fit = st["disk_used"] + extra + size[:, k] <= disk_limit
-                    live = validw[:, k] & ~stale[:, k]
-                    adm = live & fit & ~blocked
-                    blocked = blocked | (live & ~fit)
-                    adm_cols.append(adm)
-                    extra = extra + jnp.where(adm, size[:, k], 0.0)
-                admitted = jnp.stack(adm_cols, axis=1)  # [S, W]
+            admitted, extra = _window_admit(validw, size, st["disk_used"],
+                                            disk_limit, fifo=True,
+                                            stale=stale)
             st["disk_used"] = st["disk_used"] + extra
             occ3, plan = plan_links(idx, admitted, occ3)
             plan["stale"] = stale
@@ -746,13 +709,8 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
                 flat("fin_max", lambda a: a.at[rows1].max(fin_val))
 
             # -- integrate stored cloud volume (GB-seconds) per month ---------
-            # (kernel path: fused into ``gcs_admit`` above — ``gcs_used`` is
-            # final for the tick once admission has run)
-            if use_kernel:
-                st["gbsec_mo"] += gbsec_add
-            else:
-                st["gbsec_mo"] = st["gbsec_mo"].at[month].add(
-                    st["gcs_used"] / 1e9 * dt)
+            st["gbsec_mo"] = st["gbsec_mo"].at[month].add(
+                st["gcs_used"] / 1e9 * dt)
 
         with jax.named_scope(TICK_SCOPES["series"]):
             # -- opt-in series capture (end-of-tick observables) --------------
@@ -812,16 +770,13 @@ def _lane_step_fns(S: int, K: int, n_months: int, impl: TickImpl,
     return tick_fn, post_fn
 
 
-def _build_lane_sim(S: int, K: int, n_months: int, impl_name: str,
-                    record=None):
+def _build_lane_sim(S: int, K: int, n_months: int, record=None):
     """The single-lane simulation function (closure over the static
     dimensions): 5 shared tick-grid arguments + the 15 ``_LANE_FIELDS``
     arrays -> the per-lane aggregate dict. ``_grid_program`` vmaps it
     over the lane axis; ``_shard_program`` additionally shard_maps the
     vmapped program over a device mesh."""
-    tick_fn, post_fn = _lane_step_fns(S, K, n_months,
-                                      resolve_tick_impl(impl_name),
-                                      record=record)
+    tick_fn, post_fn = _lane_step_fns(S, K, n_months, record=record)
 
     def lane_sim(times, dts, month_idx, t_idx, horizon,
                  disk_limit, gcs_enabled, gcs_limit, min_pop,
@@ -897,18 +852,24 @@ _LANE_AXES = (None, None, None, None, None) + (0,) * 15
 @functools.lru_cache(maxsize=16)
 def _grid_program(S: int, K: int, n_months: int, impl_name: str,
                   record=None):
-    """The jitted lane-vmapped simulation (cached per static shape family,
-    concrete ``tick_impl`` name, and series-capture configuration; XLA
-    additionally retraces per concrete array shape — ``pack_specs``'s
-    K/J power-of-two bucketing and ``lane_chunk`` keep those shapes
-    stable across grids)."""
-    lane_sim = _build_lane_sim(S, K, n_months, impl_name, record)
+    """The jitted lane-vmapped simulation (cached per static shape family
+    and series-capture configuration; XLA additionally retraces per
+    concrete array shape — ``pack_specs``'s K/J power-of-two bucketing
+    and ``lane_chunk`` keep those shapes stable across grids).
+
+    ``impl_name`` must be ``"jnp"``, the name of the one tick
+    implementation (``repro.kernels.registry``); it is checked and
+    otherwise unused. Callers pass all five arguments positionally, so
+    that one shape family has one cache entry whoever asks for it."""
+    if impl_name != "jnp":
+        raise ValueError(f"unknown tick implementation {impl_name!r} "
+                         "(the batched engine has one: 'jnp')")
+    lane_sim = _build_lane_sim(S, K, n_months, record)
     return jax.jit(jax.vmap(lane_sim, in_axes=_LANE_AXES))
 
 
 @functools.lru_cache(maxsize=16)
-def _shard_program(S: int, K: int, n_months: int, impl_name: str,
-                   record, n_shards: int):
+def _shard_program(S: int, K: int, n_months: int, record, n_shards: int):
     """The sharded grid program: ``shard_map`` of the lane-vmapped
     simulation over a ``n_shards``-device ``"lanes"`` mesh
     (``repro.parallel.sharding.lane_mesh``).
@@ -920,22 +881,16 @@ def _shard_program(S: int, K: int, n_months: int, impl_name: str,
     The lane-axis extent of every lane argument must divide
     ``n_shards``; callers pad by replicating the last lane, exactly as
     the chunked path does. The 5 shared tick-grid arguments are
-    replicated to every device.
-
-    The Pallas kernels' outputs state no mesh axes they vary over, and
-    interpret mode's grid loop indexes lane data with unvarying indices,
-    so the kernel program is traced without ``shard_map``'s varying-axes
-    check: a type check only, which changes no value."""
+    replicated to every device."""
     from repro.parallel.sharding import LANES_AXIS, lane_mesh
 
-    lane_sim = _build_lane_sim(S, K, n_months, impl_name, record)
+    lane_sim = _build_lane_sim(S, K, n_months, record)
     mesh = lane_mesh(n_shards)
     P = jax.sharding.PartitionSpec
     in_specs = (P(),) * 5 + (P(LANES_AXIS),) * 15
     sharded = jax.shard_map(
         jax.vmap(lane_sim, in_axes=_LANE_AXES), mesh=mesh,
-        in_specs=in_specs, out_specs=P(LANES_AXIS),
-        check_vma=not resolve_tick_impl(impl_name).use_kernel)
+        in_specs=in_specs, out_specs=P(LANES_AXIS))
     return jax.jit(sharded)
 
 
@@ -947,20 +902,11 @@ _LANE_FIELDS = ("disk_limit", "gcs_enabled", "gcs_limit", "min_migrate_pop",
                 "job_submit_time", "job_tail", "jobs_per_tick")
 
 
-def simulate_packed(grid: "PackedGrid", tick_impl: str = "auto",
-                    lane_chunk: Optional[int] = None,
+def simulate_packed(grid: "PackedGrid", lane_chunk: Optional[int] = None,
                     devices: Optional[Sequence] = None,
                     record_series=None, shard: bool = False):
     """Run a packed grid on device; returns the raw per-lane aggregate dict
     (numpy arrays, lane-leading).
-
-    ``tick_impl`` selects the tick-engine implementation
-    (``repro.kernels.registry``): ``"jnp"`` | ``"pallas"`` |
-    ``"pallas_interpret"`` | ``"auto"`` (the jnp program on every
-    platform — never silently interpret mode). The
-    pre-registry ``use_pallas=``/``interpret=`` aliases are gone; a
-    boolean landing in the ``tick_impl`` slot raises with the upgrade
-    hint (``resolve_tick_impl``).
 
     ``lane_chunk`` bounds device memory: lanes execute in fixed-size
     chunks (the last chunk padded by replicating its final lane; padded
@@ -988,7 +934,6 @@ def simulate_packed(grid: "PackedGrid", tick_impl: str = "auto",
     mesh multiple); ``devices=`` is the round-robin path's knob and is
     rejected together with ``shard``.
     """
-    impl = resolve_tick_impl(tick_impl)
     record = _normalize_record(record_series, grid.n_ticks)
     if lane_chunk is not None and lane_chunk <= 0:
         raise ValueError(f"lane_chunk must be > 0, got {lane_chunk!r}")
@@ -1007,10 +952,9 @@ def simulate_packed(grid: "PackedGrid", tick_impl: str = "auto",
     tracer = get_tracer()
     S, K = len(grid.site_names), grid.max_jobs_per_tick
     if n_shards:
-        program = _shard_program(S, K, grid.n_months, impl.name, record,
-                                 n_shards)
+        program = _shard_program(S, K, grid.n_months, record, n_shards)
     else:
-        program = _grid_program(S, K, grid.n_months, impl.name, record)
+        program = _grid_program(S, K, grid.n_months, "jnp", record)
     T = grid.n_ticks
     shared = (np.asarray(grid.times), np.asarray(grid.dts),
               np.asarray(grid.month_idx), np.arange(T, dtype=np.int32),
@@ -1027,8 +971,8 @@ def simulate_packed(grid: "PackedGrid", tick_impl: str = "auto",
 
     if lane_chunk is None or lane_chunk >= L:
         C = -(-L // n_shards) * n_shards if n_shards else L
-        with tracer.span("simulate_packed", lanes=L, ticks=T,
-                         tick_impl=impl.name, chunks=1, shards=n_shards):
+        with tracer.span("simulate_packed", lanes=L, ticks=T, chunks=1,
+                         shards=n_shards):
             out = program(*shared, *pad_lanes(lanes, L, C))
             return {k: np.asarray(v)[:L] for k, v in out.items()}
 
@@ -1041,8 +985,7 @@ def simulate_packed(grid: "PackedGrid", tick_impl: str = "auto",
         chunk = pad_lanes([a[start:stop] for a in lanes], stop - start, C)
         dev = devices[ci % len(devices)]
         with tracer.span("simulate_packed.chunk", chunk=ci,
-                         lanes=stop - start, tick_impl=impl.name,
-                         shards=n_shards):
+                         lanes=stop - start, shards=n_shards):
             if len(devices) > 1 and not n_shards:
                 # commit every argument so each chunk dispatches (and can
                 # execute concurrently) on its own device
@@ -1179,9 +1122,8 @@ def lane_chunk_runner(ctx: Dict) -> Callable:
     """Build the worker-side runner for lane-chunk job payloads.
 
     ``ctx`` is the fleet init context built by ``_simulate_packed_jobs``:
-    static shapes (``S``/``K``/``n_months``), the *concrete* tick-impl
-    name (resolved in the dispatcher so ``"auto"`` cannot diverge per
-    host), the normalized series-capture config, the shard count (0 =
+    static shapes (``S``/``K``/``n_months``), the normalized
+    series-capture config, the shard count (0 =
     unsharded), and the 5 shared tick-grid arrays — shipped once at
     init, never per job. Each payload is ``{"chunk": [...15 lane
     arrays...], "n": valid_lanes}``, already padded to the program's
@@ -1189,14 +1131,12 @@ def lane_chunk_runner(ctx: Dict) -> Callable:
     program the serial path uses and truncates the padding, so fleet
     results are bitwise identical to serial ones.
     """
-    impl = resolve_tick_impl(ctx["tick_impl"])
     n_shards = int(ctx.get("shard", 0))
-    builder_args = (ctx["S"], ctx["K"], ctx["n_months"], impl.name,
-                    ctx["record"])
+    S, K, n_months, record = ctx["S"], ctx["K"], ctx["n_months"], ctx["record"]
     if n_shards:
-        program = _shard_program(*builder_args, n_shards)
+        program = _shard_program(S, K, n_months, record, n_shards)
     else:
-        program = _grid_program(*builder_args)
+        program = _grid_program(S, K, n_months, "jnp", record)
     shared = tuple(ctx["shared"])
 
     def run(payload):
@@ -1206,7 +1146,7 @@ def lane_chunk_runner(ctx: Dict) -> Callable:
     return run
 
 
-def _simulate_packed_jobs(grid: "PackedGrid", *, tick_impl: str,
+def _simulate_packed_jobs(grid: "PackedGrid", *,
                           lane_chunk: Optional[int], record_series,
                           faults, retry, job_timeout,
                           journal: Optional[Callable],
@@ -1237,7 +1177,6 @@ def _simulate_packed_jobs(grid: "PackedGrid", *, tick_impl: str,
     """
     from repro.sim import jobs as joblib
 
-    impl = resolve_tick_impl(tick_impl)
     record = _normalize_record(record_series, grid.n_ticks)
     if lane_chunk is not None and lane_chunk <= 0:
         raise ValueError(f"lane_chunk must be > 0, got {lane_chunk!r}")
@@ -1292,7 +1231,7 @@ def _simulate_packed_jobs(grid: "PackedGrid", *, tick_impl: str,
     if transport is not None:
         from repro.sim.runners import run_fleet_jobs
 
-        ctx = {"kind": "lanes", "tick_impl": impl.name, "record": record,
+        ctx = {"kind": "lanes", "record": record,
                "S": S, "K": K, "n_months": grid.n_months,
                "shard": n_shards, "shared": list(shared)}
 
@@ -1301,21 +1240,21 @@ def _simulate_packed_jobs(grid: "PackedGrid", *, tick_impl: str,
             return {"chunk": slice_chunk(start, stop), "n": stop - start}
 
         with tracer.span("simulate_packed.fleet", lanes=L, chunk=C,
-                         workers=workers or 1, tick_impl=impl.name):
+                         workers=workers or 1):
             chunk_results, registry = run_fleet_jobs(
                 jobs_list, workers=workers or 1, transport=transport,
                 ctx=ctx, prepare=prepare, policy=policy, faults=faults,
                 on_done=on_done)
     else:
         runner = lane_chunk_runner(
-            {"kind": "lanes", "tick_impl": impl.name, "record": record,
+            {"kind": "lanes", "record": record,
              "S": S, "K": K, "n_months": grid.n_months,
              "shard": n_shards, "shared": list(shared)})
 
         def run_one(job):
             start, stop = job.payload
             with tracer.span("simulate_packed.chunk", chunk=job.job_id,
-                             lanes=stop - start, tick_impl=impl.name):
+                             lanes=stop - start):
                 return runner({"chunk": slice_chunk(start, stop),
                                "n": stop - start})
 
@@ -1341,7 +1280,6 @@ def _simulate_packed_jobs(grid: "PackedGrid", *, tick_impl: str,
 
 def run_sweep_jax(specs: Sequence["ScenarioSpec"], tick: float = 10.0,
                   progress: Optional[Callable] = None,
-                  tick_impl: str = "auto",
                   lane_chunk: Optional[int] = None,
                   devices: Optional[Sequence] = None,
                   record_series=None,
@@ -1358,10 +1296,7 @@ def run_sweep_jax(specs: Sequence["ScenarioSpec"], tick: float = 10.0,
     differ only in pricing (egress option, storage price) share one
     simulated dynamics lane and are billed separately.
 
-    ``tick`` is the clock-step *duration* in seconds; ``tick_impl``
-    selects the kernel *implementation* (see ``simulate_packed`` /
-    ``repro.kernels.registry``) — independent axes despite the shared
-    prefix.
+    ``tick`` is the clock-step duration in seconds.
 
     ``lane_chunk``/``devices``: see ``simulate_packed`` — bounded-memory
     chunked execution with optional multi-device round-robin.
@@ -1400,13 +1335,12 @@ def run_sweep_jax(specs: Sequence["ScenarioSpec"], tick: float = 10.0,
     missing: set = set()
     if resilient:
         out, registry, missing = _simulate_packed_jobs(
-            grid, tick_impl=tick_impl, lane_chunk=lane_chunk,
+            grid, lane_chunk=lane_chunk,
             record_series=record_series, faults=faults, retry=retry,
             job_timeout=job_timeout, journal=journal,
             workers=workers, transport=transport, shard=shard)
     else:
-        out = simulate_packed(grid, tick_impl=tick_impl,
-                              lane_chunk=lane_chunk, devices=devices,
+        out = simulate_packed(grid, lane_chunk=lane_chunk, devices=devices,
                               record_series=record_series, shard=shard)
     wall = time.perf_counter() - t0
     reg = get_registry()

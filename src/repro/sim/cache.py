@@ -288,19 +288,11 @@ class ResultCache:
 
     # -- single-entry interface ---------------------------------------------
     def get(self, spec: "ScenarioSpec", backend: str = "process",
-            tick: Optional[float] = None,
-            tick_impl: Optional[str] = None) -> Optional[ScenarioResult]:
-        """The spec's result served from the store, or ``None`` (miss).
-
-        ``tick_impl`` (jax backend only) must be a *resolved* kernel
-        implementation name; it is part of the key, so entries from
-        different implementations never cross-serve (``"jnp"``/``None``
-        share the legacy key — see ``engine_fingerprint``).
-        """
+            tick: Optional[float] = None) -> Optional[ScenarioResult]:
+        """The spec's result served from the store, or ``None`` (miss)."""
         from repro.core.scenarios import cache_key
 
-        key = cache_key(spec, backend=backend, tick=tick,
-                        tick_impl=tick_impl)
+        key = cache_key(spec, backend=backend, tick=tick)
         reg = get_registry()
         with get_tracer().span("cache.get", key=key[:12]):
             data = self.backend.read(entry_name(key))
@@ -329,8 +321,7 @@ class ResultCache:
             return result
 
     def put(self, spec: "ScenarioSpec", result: ScenarioResult,
-            backend: str = "process", tick: Optional[float] = None,
-            tick_impl: Optional[str] = None) -> bool:
+            backend: str = "process", tick: Optional[float] = None) -> bool:
         """Store a result's dynamics payload under the spec's key.
 
         Returns ``False`` (and stores nothing) for results without raw
@@ -341,28 +332,24 @@ class ResultCache:
 
         if not result.monthly:
             return False
-        key = cache_key(spec, backend=backend, tick=tick,
-                        tick_impl=tick_impl)
-        self._write_entry(key, spec, result, backend, tick, tick_impl)
+        key = cache_key(spec, backend=backend, tick=tick)
+        self._write_entry(key, spec, result, backend, tick)
         return True
 
     # -- batch interface (what run_sweep/SweepDriver call) ------------------
     def fetch(self, specs: Iterable["ScenarioSpec"],
-              backend: str = "process", tick: Optional[float] = None,
-              tick_impl: Optional[str] = None
+              backend: str = "process", tick: Optional[float] = None
               ) -> Dict["ScenarioSpec", ScenarioResult]:
         """Served results for every spec with a stored entry (hits only)."""
         out: Dict["ScenarioSpec", ScenarioResult] = {}
         for spec in dict.fromkeys(specs):
-            result = self.get(spec, backend=backend, tick=tick,
-                              tick_impl=tick_impl)
+            result = self.get(spec, backend=backend, tick=tick)
             if result is not None:
                 out[spec] = result
         return out
 
     def store(self, pairs: Iterable[Tuple["ScenarioSpec", ScenarioResult]],
-              backend: str = "process", tick: Optional[float] = None,
-              tick_impl: Optional[str] = None) -> int:
+              backend: str = "process", tick: Optional[float] = None) -> int:
         """Store a batch of (spec, result) pairs; one write per distinct
         key (pricing variants of a lane collapse to one entry). Returns
         the number of entries written."""
@@ -373,20 +360,18 @@ class ResultCache:
         for spec, result in pairs:
             if not result.monthly:
                 continue
-            key = cache_key(spec, backend=backend, tick=tick,
-                            tick_impl=tick_impl)
+            key = cache_key(spec, backend=backend, tick=tick)
             if key in done:
                 continue
             done.add(key)
-            self._write_entry(key, spec, result, backend, tick, tick_impl)
+            self._write_entry(key, spec, result, backend, tick)
             written += 1
         return written
 
     # -- entry codec --------------------------------------------------------
     def _write_entry(self, key: str, spec: "ScenarioSpec",
                      result: ScenarioResult, backend: str,
-                     tick: Optional[float],
-                     tick_impl: Optional[str] = None) -> None:
+                     tick: Optional[float]) -> None:
         from repro.core.scenarios import (RESULT_SCHEMA_VERSION,
                                           dynamics_key, engine_fingerprint)
 
@@ -395,12 +380,10 @@ class ResultCache:
             "key": key,
             "manifest": {
                 "spec": asdict(dynamics_key(spec)),
-                "engine": engine_fingerprint(backend, tick, tick_impl),
+                "engine": engine_fingerprint(backend, tick),
                 "backend": backend,
                 "tick": None if backend == "process" else float(
                     10.0 if tick is None else tick),
-                "tick_impl": (None if backend == "process"
-                              else tick_impl or "jnp"),
                 "package_version": __version__,
                 "python": sys.version.split()[0],
                 "numpy": np.__version__,

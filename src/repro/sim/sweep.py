@@ -319,15 +319,20 @@ def _jobs_engaged(backend: str, retry: Any, faults: Any,
             or faults is not None or transport is not None)
 
 
+def _on_accelerator() -> bool:
+    """True when JAX's default backend is an accelerator (tpu/gpu)."""
+    import jax
+
+    return jax.default_backend() in ("tpu", "gpu")
+
+
 def _refuse_fleet_on_accelerator(backend: str, transport: Any) -> None:
     """Raise when jax-backend lanes would go to the worker fleet from an
     accelerator host. Fleet workers pin ``JAX_PLATFORMS=cpu``, and a chip
     belongs to one process, so the lanes would run on the host CPU."""
     if backend != "jax" or transport is None:
         return
-    from repro.kernels.registry import on_accelerator
-
-    if on_accelerator():
+    if _on_accelerator():
         raise ValueError(
             "backend='jax' with transport= on an accelerator host: fleet "
             "workers run on the CPU, and a chip belongs to one process, so "
@@ -335,8 +340,7 @@ def _refuse_fleet_on_accelerator(backend: str, transport: Any) -> None:
             "spread lanes over this host's devices")
 
 
-def _journal_to_cache(cache: Any, backend: str, tick: float,
-                      tick_impl: Optional[str]) -> Callable:
+def _journal_to_cache(cache: Any, backend: str, tick: float) -> Callable:
     """A per-job completion hook that checkpoints results into the
     persistent cache as they finish (the resume mechanism: a killed run
     re-executed with the same cache recomputes only unfinished jobs).
@@ -354,14 +358,12 @@ def _journal_to_cache(cache: Any, backend: str, tick: float,
         for spec, result in pairs:
             if not result.monthly:
                 continue
-            key = cache_key(spec, backend=backend, tick=tick,
-                            tick_impl=tick_impl)
+            key = cache_key(spec, backend=backend, tick=tick)
             if key not in seen:
                 seen.add(key)
                 fresh.append((spec, result))
         if fresh:
-            cache.store(fresh, backend=backend, tick=tick,
-                        tick_impl=tick_impl)
+            cache.store(fresh, backend=backend, tick=tick)
 
     return journal
 
@@ -369,7 +371,7 @@ def _journal_to_cache(cache: Any, backend: str, tick: float,
 def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
               progress: Optional[Callable[[int, int, ScenarioResult], None]]
               = None, backend: str = "process",
-              tick: float = 10.0, tick_impl: str = "auto",
+              tick: float = 10.0,
               lane_chunk: Optional[int] = None,
               devices: Optional[Sequence[Any]] = None,
               cache: Optional[Any] = None,
@@ -392,12 +394,6 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
       grid and matches the reference statistically (Table 2 tolerance),
       not bitwise; ``tick`` sets its clock step in seconds.
 
-    ``tick_impl`` (jax backend only) selects the tick-engine *kernel
-    implementation* — ``"jnp"`` | ``"pallas"`` | ``"pallas_interpret"``
-    | ``"auto"`` (``repro.kernels.registry``; ``"auto"`` resolves to the
-    jnp program on every platform). Not to be confused with ``tick``,
-    the clock-step *duration*.
-
     ``workers``: process count for the process backend; ``None`` uses all
     CPUs (capped at the batch size), ``0``/``1`` runs serially in-process
     (useful under profilers and in tests of determinism).
@@ -414,10 +410,6 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
     pricing fields, bit-identical to a fresh run on the same engine),
     only the misses are simulated, and their results are stored back.
     ``SweepResult.lanes_simulated``/``cache_hits`` report the split.
-    ``tick_impl`` is resolved to its concrete implementation *before*
-    keying, so entries from different kernel implementations never
-    cross-serve (``"jnp"`` keeps the legacy key: it is bitwise the
-    pre-registry engine).
 
     ``record_series`` (jax backend only): per-tick series capture —
     ``True`` samples every tick, an int is the sample stride in ticks;
@@ -456,8 +448,6 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
     Python loop. Per-lane results stay bitwise identical (lane programs
     exchange no collectives). Mutually exclusive with ``devices``.
     """
-    if backend != "jax" and tick_impl != "auto":
-        raise ValueError("tick_impl applies to backend='jax' only")
     if backend != "jax" and record_series not in (None, False):
         raise ValueError("record_series applies to backend='jax' only "
                          "(the process backend records curves via "
@@ -468,11 +458,6 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
     from repro.sim.faults import as_faults
 
     faults = as_faults(faults)
-    impl_name: Optional[str] = None
-    if backend == "jax":
-        from repro.kernels.registry import resolve_tick_impl
-
-        impl_name = resolve_tick_impl(tick_impl).name
     if cache is not None:
         from repro.core.scenarios import dynamics_key
         from repro.sim.cache import ResultCache, as_cache  # imports us
@@ -488,17 +473,15 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
         specs = list(specs)
         t0 = time.perf_counter()
         engaged = _jobs_engaged(backend, retry, faults, transport)
-        hits = cache.fetch(specs, backend=backend, tick=tick,
-                           tick_impl=impl_name)
+        hits = cache.fetch(specs, backend=backend, tick=tick)
         miss = [s for s in dict.fromkeys(specs) if s not in hits]
         computed: Dict["ScenarioSpec", ScenarioResult] = {}
         failures: List[Any] = []
         if miss:
-            journal = (_journal_to_cache(cache, backend, tick, impl_name)
+            journal = (_journal_to_cache(cache, backend, tick)
                        if engaged else None)
             res = run_sweep(miss, workers=workers, progress=progress,
                             backend=backend, tick=tick,
-                            tick_impl=impl_name or "auto",
                             lane_chunk=lane_chunk, devices=devices,
                             record_series=record_series,
                             retry=retry, faults=faults,
@@ -510,8 +493,7 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
             failures = list(res.failures)
             if not engaged:
                 # The plain jax path has no per-job journal; store in bulk.
-                cache.store(computed.items(), backend=backend, tick=tick,
-                            tick_impl=impl_name)
+                cache.store(computed.items(), backend=backend, tick=tick)
         merged = {**hits, **computed}
         return SweepResult(
             results=[merged[s] for s in specs if s in merged],
@@ -523,7 +505,6 @@ def run_sweep(specs: Sequence[ScenarioSpec], workers: Optional[int] = None,
         from repro.sim.batched import run_sweep_jax  # deferred: needs jax
 
         return run_sweep_jax(specs, tick=tick, progress=progress,
-                             tick_impl=impl_name,
                              lane_chunk=lane_chunk, devices=devices,
                              record_series=record_series,
                              retry=retry, faults=faults,
@@ -617,7 +598,6 @@ class SweepDriver:
 
     def __init__(self, backend: str = "jax", tick: float = 10.0,
                  workers: Optional[int] = None,
-                 tick_impl: str = "auto",
                  lane_chunk: Optional[int] = None,
                  devices: Optional[Sequence[Any]] = None,
                  progress: Optional[Callable[[int, int, ScenarioResult],
@@ -629,8 +609,6 @@ class SweepDriver:
                  job_timeout: Optional[float] = None,
                  transport: Optional[Any] = None,
                  shard: bool = False):
-        if backend != "jax" and tick_impl != "auto":
-            raise ValueError("tick_impl applies to backend='jax' only")
         if backend != "jax" and record_series not in (None, False):
             raise ValueError("record_series applies to backend='jax' only")
         if shard and backend != "jax":
@@ -640,11 +618,7 @@ class SweepDriver:
 
         self.backend = backend
         self.tick = tick
-        self.tick_impl = tick_impl
         self.record_series = record_series
-        #: resolved lazily on first run (importing jax to resolve
-        #: ``"auto"`` is deferred until the jax backend actually runs)
-        self._impl_name: Optional[str] = None
         self.workers = workers
         self.lane_chunk = lane_chunk
         self.devices = devices
@@ -676,18 +650,6 @@ class SweepDriver:
     def __call__(self, specs: Sequence["ScenarioSpec"]) -> SweepResult:
         return self.run(specs)
 
-    def _resolved_impl(self) -> Optional[str]:
-        """The concrete ``tick_impl`` name for cache keying (jax backend
-        only; resolving ``"auto"`` imports jax, so it happens on first
-        use and is then pinned for the driver's lifetime)."""
-        if self.backend != "jax":
-            return None
-        if self._impl_name is None:
-            from repro.kernels.registry import resolve_tick_impl
-
-            self._impl_name = resolve_tick_impl(self.tick_impl).name
-        return self._impl_name
-
     def run(self, specs: Sequence["ScenarioSpec"]) -> SweepResult:
         """Results for ``specs`` in order, simulating only the unseen ones."""
         from repro.core.scenarios import dynamics_key
@@ -698,8 +660,7 @@ class SweepDriver:
         hits = 0
         if new and self.cache is not None:
             served = self.cache.fetch(new, backend=self.backend,
-                                      tick=self.tick,
-                                      tick_impl=self._resolved_impl())
+                                      tick=self.tick)
             self._memo.update(served)
             hits = len(served)
             self.cache_hits += hits
@@ -712,12 +673,10 @@ class SweepDriver:
             journal = None
             if self.cache is not None and engaged:
                 journal = _journal_to_cache(self.cache, self.backend,
-                                            self.tick,
-                                            self._resolved_impl())
+                                            self.tick)
             res = run_sweep(new, workers=self.workers,
                             progress=self.progress, backend=self.backend,
                             tick=self.tick,
-                            tick_impl=self._resolved_impl() or "auto",
                             lane_chunk=self.lane_chunk,
                             devices=self.devices,
                             record_series=self.record_series,
@@ -737,8 +696,7 @@ class SweepDriver:
             self.failures.extend(round_failures)
             if self.cache is not None and not engaged:
                 self.cache.store(((r.spec, r) for r in res.results),
-                                 backend=self.backend, tick=self.tick,
-                                 tick_impl=self._resolved_impl())
+                                 backend=self.backend, tick=self.tick)
         reg = get_registry()
         reg.set_gauge("lanes.simulated", self.lanes_simulated,
                       help="Distinct dynamics lanes simulated by the "

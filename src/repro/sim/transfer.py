@@ -17,9 +17,8 @@ size/throughput`` under a FIFO ``max_active`` slot queue, so it schedules
 completion events directly instead of ticking — identical aggregate
 statistics at ~100x less work (cross-validated in tests).
 
-The tick update math is also what ``repro.kernels.carousel_update``
-implements as a TPU Pallas kernel (the paper's stated linear-scaling hot
-loop, vectorized over transfers).
+The tick update math, vectorized over transfers, is also the batched
+engine's transfer advance (``repro.sim.batched._advance_transfers``).
 """
 
 from __future__ import annotations
@@ -136,11 +135,11 @@ class EventDrivenTransferService:
 
 @dataclass(frozen=True)
 class LinkTickTable:
-    """Dense link-parameter arrays for fixed-tick (batched/kernel) engines.
+    """Dense link-parameter arrays for fixed-tick (batched) engines.
 
     The tick adapter between object-graph links and the vectorized
-    transfer-tick math (``repro.kernels.carousel_update`` and the
-    ``repro.sim.batched`` lane-per-scenario backend): link ``m`` advances an
+    transfer-tick math (the ``repro.sim.batched`` lane-per-scenario
+    backend): link ``m`` advances an
     active transfer by ``bw[m] * dt`` bytes per tick (throughput mode) or
     ``bw[m]/count * dt`` (shared mode), holds at most ``slots[m]`` concurrent
     transfers, and defers progress by ``latency[m]`` seconds after a slot is

@@ -34,6 +34,7 @@ from repro.sim.sweep import run_sweep
 TOL = 0.05
 
 TINY = dict(days=0.25, n_files=1000)
+QUICK = dict(days=0.1, n_files=1000)
 
 
 def _close(a, b, tol=TOL, floor=1.0):
@@ -180,32 +181,70 @@ def test_queue_heads_equal_top_k(case):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
-@pytest.mark.parametrize("tick_impl", ["jnp", "pallas_interpret"])
+#: The benchmark's three deployments, cut to 1000 files and a 1 TB disk
+#: per site: cfg III, cfg II, and cfg III under a bucket quota, here
+#: 1 TB, so that it binds as the gcs20 cell's 20 TB does.
+QUEUE_DEPLOYMENTS = {
+    "cfgIII": dict(base="III"),
+    "cfgII": dict(base="II"),
+    "gcs20": dict(base="III", gcs_limit_tb=1.0),
+}
+
+
+@pytest.mark.parametrize("deployment", sorted(QUEUE_DEPLOYMENTS))
 def test_queue_heads_program_bitwise_equal_to_top_k(monkeypatch,
-                                                    tick_impl):
+                                                    deployment):
     """The whole grid program, with its queue heads taken by ``top_k``
     and by ``_queue_heads``, gives bitwise equal per-lane outputs on a
     small disk whose waiting queue runs far deeper than W."""
-    specs = with_seeds([ScenarioSpec(base=b, cache_tb=1.0, **QUICK)
-                        for b in ("III", "II")], 2)
+    specs = with_seeds([ScenarioSpec(cache_tb=1.0, **QUICK,
+                                     **QUEUE_DEPLOYMENTS[deployment])], 2)
     grid = pack_specs(specs, tick=60.0)
     batched._grid_program.cache_clear()
     try:
         with monkeypatch.context() as m:
             m.setattr(batched, "_queue_heads",
                       lambda t, w: jax.lax.top_k(-t, w))
-            by_top_k = simulate_packed(grid, tick_impl=tick_impl,
-                                       record_series=True)
+            by_top_k = simulate_packed(grid, record_series=True)
         batched._grid_program.cache_clear()
-        heads = simulate_packed(grid, tick_impl=tick_impl,
-                                record_series=True)
+        heads = simulate_packed(grid, record_series=True)
     finally:
         batched._grid_program.cache_clear()
     assert heads["ser_queue"].max() > 10 * W
+    if deployment == "gcs20":
+        assert (heads["gcs_refused_ticks"] > 0).all()  # the quota binds
     assert set(heads) == set(by_top_k)
     for key in heads:
         np.testing.assert_array_equal(heads[key], by_top_k[key],
                                       err_msg=key)
+
+
+def test_benchmark_names_the_sweeps_program():
+    """The chip benchmark builds its programs as
+    ``_grid_program(S, K, n_months, resolve_tick_impl("auto").name,
+    None)``: that is the cache entry a plain ``simulate_packed`` call
+    uses, so a program the benchmark compiles is the one a request runs.
+    No other implementation name is accepted."""
+    from repro.kernels.registry import resolve_tick_impl
+
+    grid = pack_specs([ScenarioSpec(**QUICK)], tick=60.0)
+    S, K = len(grid.site_names), grid.max_jobs_per_tick
+    batched._grid_program.cache_clear()
+    try:
+        simulate_packed(grid)
+        assert batched._grid_program.cache_info().currsize == 1
+        name = resolve_tick_impl("auto").name
+        assert name == resolve_tick_impl("jnp").name == "jnp"
+        batched._grid_program(S, K, grid.n_months, name, None)
+        info = batched._grid_program.cache_info()
+        assert (info.hits, info.currsize) == (1, 1)
+    finally:
+        batched._grid_program.cache_clear()
+    for other in ("pallas", "pallas_interpret", "cuda"):
+        with pytest.raises(ValueError, match="tick implementation"):
+            resolve_tick_impl(other)
+        with pytest.raises(ValueError, match="tick implementation"):
+            batched._grid_program.__wrapped__(S, K, grid.n_months, other)
 
 
 # ------------------------------------------- lane chunking & shape buckets
@@ -276,13 +315,12 @@ def test_shard_map_bitwise_identical():
 
 
 def test_shard_map_bitwise_identical_on_four_devices():
-    """``shard=True`` over 4 (virtual CPU) devices: the scan carry, the
-    cloud gate's loop carry and the Pallas kernels' outputs must vary
-    over the lane axis like the tick's output, and per-lane results must
-    equal the unsharded program's bitwise, for the jnp program and the
-    kernels in interpret mode, with a lane whose bucket quota binds. Runs
-    in a subprocess so the forced device count does not leak into this
-    test session."""
+    """``shard=True`` over 4 (virtual CPU) devices: the scan carry and
+    the cloud gate's loop carry must vary over the lane axis like the
+    tick's output, and per-lane results must equal the unsharded
+    program's bitwise, whole and chunked, with a lane whose bucket quota
+    binds. Runs in a subprocess so the forced device count does not leak
+    into this test session."""
     import os
     import subprocess
     import sys
@@ -300,18 +338,15 @@ def test_shard_map_bitwise_identical_on_four_devices():
         specs.append(ScenarioSpec(base="III", cache_tb=0.5, gcs_limit_tb=0.1,
                                   seed=7, days=0.05, n_files=300))
         grid = pack_specs(specs, tick=60.0)
-        runs = (("jnp", (None, 2)), ("pallas_interpret", (None,)))
-        for impl, chunks in runs:
-            plain = simulate_packed(grid, tick_impl=impl)
-            for chunk in chunks:
-                sharded = simulate_packed(grid, shard=True, lane_chunk=chunk,
-                                          tick_impl=impl)
-                assert set(sharded) == set(plain)
-                for key in plain:
-                    np.testing.assert_array_equal(plain[key], sharded[key],
-                                                  err_msg=impl + " " + key)
-            assert plain["gcs_refused_ticks"][-1] > 0  # the quota binds
-            assert plain["gcs_gate_passes"][-1] > 1
+        plain = simulate_packed(grid)
+        for chunk in (None, 2):
+            sharded = simulate_packed(grid, shard=True, lane_chunk=chunk)
+            assert set(sharded) == set(plain)
+            for key in plain:
+                np.testing.assert_array_equal(plain[key], sharded[key],
+                                              err_msg=key)
+        assert plain["gcs_refused_ticks"][-1] > 0  # the quota binds
+        assert plain["gcs_gate_passes"][-1] > 1
         print("OK")
     """)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -327,10 +362,10 @@ def test_jax_transport_refused_on_accelerator(monkeypatch):
     process, so on an accelerator host ``backend="jax"`` with a
     ``transport`` raises (pointing at ``shard=True``) instead of quietly
     simulating on the host CPU. The process backend's fleet is fine."""
-    from repro.kernels import registry
+    from repro.sim import sweep
     from repro.sim.sweep import SweepDriver
 
-    monkeypatch.setattr(registry, "_platform", lambda: "tpu")
+    monkeypatch.setattr(sweep, "_on_accelerator", lambda: True)
     spec = ScenarioSpec(days=0.05, n_files=300)
     for transport in ("local", "subprocess"):
         with pytest.raises(ValueError, match="shard=True"):
@@ -339,7 +374,7 @@ def test_jax_transport_refused_on_accelerator(monkeypatch):
             SweepDriver(backend="jax", transport=transport)
     assert run_sweep([spec], backend="process", transport="local",
                      workers=1).ok
-    monkeypatch.setattr(registry, "_platform", lambda: "cpu")
+    monkeypatch.setattr(sweep, "_on_accelerator", lambda: False)
     SweepDriver(backend="jax", transport="local")  # CPU host: allowed
 
 
@@ -540,111 +575,6 @@ def test_workload_shapes_move_the_observables(workload_grid):
     assert by["zipf-drift"].metrics["jobs_submitted"] == steady
     assert by["campaign"].metrics["jobs_submitted"] == \
         pytest.approx(steady, rel=0.05)
-
-
-# ------------------------------------------- tick_impl selection (ISSUE 7)
-QUICK = dict(days=0.1, n_files=1000)
-
-
-@pytest.fixture(scope="module")
-def impl_grid():
-    """A pricing-deduplicating grid run under every CPU-runnable
-    tick_impl (same specs, tick=60 to keep the interpret path quick)."""
-    specs = expand_grid({
-        "base": "III", "cache_tb": [10.0, 25.0],
-        "egress": ["internet", "direct"],
-        "gcs_limit_tb": [None, 5.0], "seed": 1, **QUICK,
-    })
-    out = {impl: run_sweep(specs, backend="jax", tick=60.0, tick_impl=impl)
-           for impl in ("jnp", "pallas_interpret", "auto")}
-    return specs, out
-
-
-def test_tick_impl_interpret_parity_small_grid(impl_grid):
-    """The fused Pallas kernels (interpret mode) track the jnp oracle at
-    the Table 2 bar. Agreement is statistical, not bitwise: the blocked
-    GCS-admission cumsum reassociates floats, so capacity-boundary ties
-    can admit a different file."""
-    _, out = impl_grid
-    _assert_lane_parity(out["jnp"], out["pallas_interpret"])
-
-
-def test_tick_impl_auto_resolves_to_jnp_on_cpu(impl_grid):
-    """"auto" must be the jnp program *bitwise* — never a silent
-    interpret-mode fallback (registry resolution contract). It resolves
-    to jnp on every platform, so this holds on an accelerator too."""
-    _, out = impl_grid
-    for a, b in zip(out["jnp"].results, out["auto"].results):
-        assert a.spec == b.spec
-        assert a.metrics == b.metrics, a.spec.label
-        assert a.cost_usd == b.cost_usd
-
-
-def test_tick_impl_interpret_deterministic(impl_grid):
-    specs, out = impl_grid
-    again = run_sweep(specs, backend="jax", tick=60.0,
-                      tick_impl="pallas_interpret")
-    for a, b in zip(out["pallas_interpret"].results, again.results):
-        assert a.metrics == b.metrics, a.spec.label
-        assert a.cost_usd == b.cost_usd
-
-
-def test_tick_impl_interpret_parity_216_config_grid():
-    """ISSUE 7 acceptance: interpret-mode kernels vs the jnp oracle on
-    the 216-config bench pricing grid (4 cache x 3 egress x 9 prices x
-    2 seeds — 8 dynamics lanes after pricing dedup), within the Table 2
-    5% tolerance per config."""
-    specs = with_seeds(expand_grid({
-        "base": "III",
-        "cache_tb": [10.0, 20.0, 40.0, 80.0],
-        "egress": ["internet", "direct", "interconnect"],
-        "storage_price": [round(0.018 + 0.002 * i, 3) for i in range(9)],
-        **QUICK,
-    }), 2)
-    assert len(specs) == 216
-    jnp_out = run_sweep(specs, backend="jax", tick=60.0, tick_impl="jnp")
-    pal_out = run_sweep(specs, backend="jax", tick=60.0,
-                        tick_impl="pallas_interpret")
-    _assert_lane_parity(jnp_out, pal_out)
-
-
-@pytest.mark.slow
-def test_tick_impl_interpret_matches_reference_table2_bar():
-    """Slow acceptance: the kernel path holds the same Table 2 bar
-    against the event-driven *reference* engine that the jnp program is
-    held to (0.75-day horizon; see the 64-config grid note)."""
-    specs = with_seeds(expand_grid({
-        "base": "III", "cache_tb": [10.0, 40.0],
-        "egress": ["internet", "direct"],
-        "days": 0.75, "n_files": 1000,
-    }), 2)
-    ref = run_sweep(specs, workers=2)
-    pal = run_sweep(specs, backend="jax", tick_impl="pallas_interpret")
-    _assert_lane_parity(ref, pal)
-
-
-def test_tick_impl_knob_validation():
-    with pytest.raises(ValueError, match="tick_impl"):
-        run_sweep([ScenarioSpec(**TINY)], backend="jax",
-                  tick_impl="fortran")
-    with pytest.raises(ValueError, match="jax"):
-        run_sweep([ScenarioSpec(**TINY)], backend="process",
-                  tick_impl="pallas_interpret")
-    # "auto" is the neutral default and valid for every backend
-    run_sweep([ScenarioSpec(days=0.1, n_files=100)], backend="process",
-              tick_impl="auto")
-
-
-def test_simulate_packed_use_pallas_removed():
-    """The use_pallas= alias is gone: the keyword no longer exists, and
-    a legacy positional boolean in the tick_impl slot raises with the
-    upgrade hint instead of routing through the removed shim."""
-    spec = ScenarioSpec(base="III", cache_tb=15.0, seed=0, **QUICK)
-    grid = pack_specs([spec], tick=60.0)
-    with pytest.raises(TypeError, match="use_pallas"):
-        simulate_packed(grid, use_pallas=False)
-    with pytest.raises(ValueError, match="tick_impl"):
-        simulate_packed(grid, False)
 
 
 # ------------------------------------------- acceptance grid (64 configs)

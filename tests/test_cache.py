@@ -115,85 +115,43 @@ def test_cache_key_fingerprints_the_engine():
         engine_fingerprint("cuda")
 
 
-def test_engine_fingerprint_tick_impl_axis():
-    """ISSUE 7: the kernel implementation is part of the engine identity.
-    ``"jnp"`` (and the ``None`` default) keep the pre-registry fingerprint
-    — the jnp program IS the legacy engine bit-for-bit, so existing
-    entries stay warm — while the Pallas impls get their own suffix (XLA
-    fuses the kernel trace differently: ulp-level divergence; and the
-    blocked admission cumsum reassociates floats)."""
-    assert engine_fingerprint("jax", 60.0, "jnp") == "jax:60"
-    assert engine_fingerprint("jax", 60.0, None) == "jax:60"
-    assert engine_fingerprint("jax", 60.0, "pallas") == "jax:60:pallas"
-    assert engine_fingerprint("jax", 60.0, "pallas_interpret") == \
-        "jax:60:pallas_interpret"
+def test_jax_fingerprint_is_the_legacy_key():
+    """The batched engine's fingerprint is ``"jax:<tick>"``, and its key
+    the hash of the same canonical document as ever, so every entry
+    already stored for the batched engine keeps its key."""
+    import hashlib
+    from dataclasses import asdict
+
+    from repro.core.scenarios import dynamics_key
+
+    assert engine_fingerprint("jax", 60.0) == "jax:60"
+    assert engine_fingerprint("jax") == "jax:10"
     assert engine_fingerprint("process") == "process"
-    # "auto" must be resolved per host BEFORE keying: an auto-keyed entry
-    # written on a CPU host would silently cross-serve on a TPU host
-    with pytest.raises(ValueError, match="auto"):
-        engine_fingerprint("jax", 60.0, "auto")
-
-
-def test_cache_key_stable_under_tick_impl_axis():
-    """Key-stability contract: adding the tick_impl axis moved no
-    existing key (jnp/None), and resolved impls never collide."""
     spec = ScenarioSpec(**TINY)
-    legacy = cache_key(spec, "jax", 60.0)
-    assert cache_key(spec, "jax", 60.0, tick_impl="jnp") == legacy
-    assert cache_key(spec, "jax", 60.0, tick_impl=None) == legacy
-    keys = {legacy,
-            cache_key(spec, "jax", 60.0, tick_impl="pallas"),
-            cache_key(spec, "jax", 60.0, tick_impl="pallas_interpret"),
-            cache_key(spec, "jax", 10.0, tick_impl="pallas")}
-    assert len(keys) == 4
-    assert cache_key(spec, "process") == \
-        cache_key(spec, "process", tick_impl=None)
+    doc = {"schema": RESULT_SCHEMA_VERSION, "engine": "jax:60",
+           "spec": asdict(dynamics_key(spec))}
+    blob = json.dumps(doc, sort_keys=True, separators=(",", ":"))
+    assert cache_key(spec, "jax", 60.0) == \
+        hashlib.sha256(blob.encode("utf-8")).hexdigest()
 
 
-def test_tick_impl_entries_never_cross_serve(tmp_path):
-    """A lane simulated by the Pallas kernels must not serve a jnp
-    request (or vice versa) — the impls are only statistically equal."""
-    spec = ScenarioSpec(**TINY)
-    specs = [spec]
-    fresh = run_sweep(specs, backend="jax", tick=60.0,
-                      tick_impl="pallas_interpret")
+def test_entry_written_with_tick_impl_metadata_still_hits(tmp_path,
+                                                          tiny_result):
+    """Entries of the batched engine stored with a ``"tick_impl": "jnp"``
+    field in their manifest are served as they are."""
+    spec, result = tiny_result
     cache = ResultCache(tmp_path)
-    assert cache.store(zip(specs, fresh.results), backend="jax", tick=60.0,
-                       tick_impl="pallas_interpret") == 1
-    assert cache.get(spec, backend="jax", tick=60.0) is None
-    assert cache.get(spec, backend="jax", tick=60.0,
-                     tick_impl="jnp") is None
-    served = cache.get(spec, backend="jax", tick=60.0,
-                       tick_impl="pallas_interpret")
+    assert cache.put(spec, result, backend="jax", tick=60.0)
+    path = _entry_path(tmp_path, spec, backend="jax", tick=60.0)
+    with open(path) as f:
+        doc = json.load(f)
+    assert "tick_impl" not in doc["manifest"]
+    doc["manifest"]["tick_impl"] = "jnp"
+    with open(path, "w") as f:
+        json.dump(doc, f)
+    served = ResultCache(tmp_path).get(spec, backend="jax", tick=60.0)
     assert served is not None
-    _same_result(served, fresh.results[0])
-    # the manifest records which kernels produced the entry
-    name = entry_name(cache_key(spec, "jax", 60.0,
-                                tick_impl="pallas_interpret"))
-    doc = json.loads(open(os.path.join(str(tmp_path), name)).read())
-    assert doc["manifest"]["tick_impl"] == "pallas_interpret"
-    assert doc["manifest"]["engine"] == "jax:60:pallas_interpret"
-
-
-def test_sweep_cache_keys_by_resolved_impl(tmp_path):
-    """``run_sweep(cache=...)`` resolves "auto" before keying, so a warm
-    re-run with the explicit resolved name hits the same entries."""
-    from repro.kernels.registry import resolve_tick_impl
-
-    specs = with_seeds([ScenarioSpec(**TINY)], 2)
-    cold = run_sweep(specs, backend="jax", tick=60.0, cache=str(tmp_path))
-    assert cold.lanes_simulated == 2 and cold.cache_hits == 0
-    resolved = resolve_tick_impl("auto").name
-    warm = run_sweep(specs, backend="jax", tick=60.0, tick_impl=resolved,
-                     cache=str(tmp_path))
-    assert warm.lanes_simulated == 0 and warm.cache_hits == 2
-    for a, b in zip(cold.results, warm.results):
-        _same_result(a, b)
-    # a different concrete impl is a cold start, not a cross-serve
-    other = "pallas_interpret" if resolved != "pallas_interpret" else "jnp"
-    cold2 = run_sweep(specs, backend="jax", tick=60.0, tick_impl=other,
-                      cache=str(tmp_path))
-    assert cold2.cache_hits == 0 and cold2.lanes_simulated == 2
+    _same_result(served, result)
 
 
 def test_cache_key_stable_across_process_restart():

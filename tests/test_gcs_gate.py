@@ -65,25 +65,36 @@ GATE_CASES = {
     # cfg II: no tier, so no file is ever a migration candidate
     "disabled": lambda r: (np.zeros(10_000, bool), _table3_sizes(r, 10_000),
                            np.float32(0.0), np.float32(0.0)),
+    "binding_quota": lambda r: _binding_quota(np.random.default_rng(7)),
 }
 
 
+def _binding_quota(rng):
+    """The tick's ``[S, F]`` planes (S=4, F=33), scanned site-major:
+    whole-MiB files up to about 1 GB and 18 GiB of room left, less than
+    the candidates need, so the gate takes more than one pass."""
+    want = rng.random((4, 33)) < 0.4
+    sizes = (rng.integers(1, 954, (4, 33)) * MiB).astype(np.float32)
+    return want, sizes, np.float32(2 * 2.0 ** 30), np.float32(20 * 2.0 ** 30)
+
+
 def _plain_first_fit(want, sizes, used, limit):
-    """Scan the candidates in order; admit each one that fits."""
-    admitted = np.zeros_like(want)
+    """Scan the candidates in order (site-major, for a ``[S, F]`` plane);
+    admit each one that fits."""
+    admitted = np.zeros(want.size, bool)
     used = float(used)
     for i in np.flatnonzero(want):
-        if used + float(sizes[i]) <= float(limit):
+        if used + float(sizes.flat[i]) <= float(limit):
             admitted[i] = True
-            used += float(sizes[i])
-    return admitted, np.float32(used)
+            used += float(sizes.flat[i])
+    return admitted.reshape(want.shape), np.float32(used)
 
 
 @pytest.mark.parametrize("case", sorted(GATE_CASES))
 def test_gate_equals_plain_first_fit(case):
     want, sizes, used, limit = GATE_CASES[case](np.random.default_rng(3))
-    admitted, used_out, passes, _ = jax.jit(_gcs_first_fit)(want, sizes,
-                                                            used, limit)
+    admitted, used_out, passes = jax.jit(_gcs_first_fit)(want, sizes, used,
+                                                         limit)
     plain, plain_used = _plain_first_fit(want, sizes, used, limit)
     np.testing.assert_array_equal(np.asarray(admitted), plain)
     assert np.asarray(used_out).tobytes() == plain_used.tobytes()
@@ -92,11 +103,12 @@ def test_gate_equals_plain_first_fit(case):
         assert int(passes) == 1 and (plain == want).all()
     elif case in ("no_candidates", "disabled", "full"):
         assert int(passes) == 0
-    if case in ("oversized_blockers", "one_tib_left", "nearly_full"):
+    if case in ("oversized_blockers", "one_tib_left", "nearly_full",
+                "binding_quota"):
         # the quota binds: something waits, and a later file went in past
         # the first one that did not fit
         refused = np.flatnonzero(want & ~plain)
-        assert refused.size and plain[refused[0]:].any()
+        assert refused.size and plain.reshape(-1)[refused[0]:].any()
         assert int(passes) > (1 if case != "nearly_full" else 0)
 
 
@@ -108,7 +120,7 @@ def test_gate_lanes_are_independent():
                                           "unlimited", "disabled",
                                           "one_tib_left")]
     want, sizes, used, limit = (np.stack(x) for x in zip(*cases))
-    admitted, used_out, passes, _ = jax.jit(jax.vmap(_gcs_first_fit))(
+    admitted, used_out, passes = jax.jit(jax.vmap(_gcs_first_fit))(
         want, sizes, used, limit)
     for li, case in enumerate(cases):
         plain, plain_used = _plain_first_fit(*case)
@@ -118,7 +130,7 @@ def test_gate_lanes_are_independent():
     assert int(passes[0]) > 1 and int(passes[3]) > 1
 
 
-def _three_pass_gate(want, sizes, used, limit, gate_pass=None, aux=()):
+def _three_pass_gate(want, sizes, used, limit):
     """The gate before first fit: three prefix-sum passes over the
     flattened planes, each over the candidates not yet admitted, a
     blocker left among them."""
@@ -130,7 +142,7 @@ def _three_pass_gate(want, sizes, used, limit, gate_pass=None, aux=()):
         new = rem & (used + csum <= limit)
         used = used + jax.numpy.sum(sizes_flat * new)
         admitted = admitted | new
-    return admitted.reshape(want.shape), used, jax.numpy.int32(3), aux
+    return admitted.reshape(want.shape), used, jax.numpy.int32(3)
 
 
 @pytest.mark.parametrize("case", ["unlimited", "disabled"])

@@ -91,13 +91,13 @@ class TestMetricsRegistry:
     def test_prometheus_exposition(self):
         r = MetricsRegistry()
         r.inc("cache.hits", 3, help="Result-cache lookup hits")
-        r.inc("tick_impl.resolved", impl="jnp")
+        r.inc("faults.injected", kind="corrupt")
         r.observe("wall_s", 0.3)
         text = r.to_prometheus()
         assert "# HELP cache_hits Result-cache lookup hits" in text
         assert "# TYPE cache_hits counter" in text
         assert "cache_hits 3" in text
-        assert 'tick_impl_resolved{impl="jnp"} 1' in text
+        assert 'faults_injected{kind="corrupt"} 1' in text
         assert 'wall_s_bucket{le="+Inf"} 1' in text
         assert "wall_s_count 1" in text
 

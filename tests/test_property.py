@@ -109,28 +109,6 @@ def test_egress_cost_monotone_and_tiered(nbytes):
     assert 0.08 - 1e-9 <= rate <= 0.12 + 1e-9
 
 
-@given(st.integers(1, 400), st.integers(1, 12), st.integers(0, 2**31 - 1),
-       st.floats(0.5, 20.0))
-@settings(max_examples=30, deadline=None)
-def test_carousel_kernel_matches_ref_property(n, m, seed, dt):
-    import jax.numpy as jnp
-    from repro.kernels.carousel_update.ops import carousel_tick
-
-    rng = np.random.default_rng(seed)
-    link_id = jnp.asarray(rng.integers(0, m, n), jnp.int32)
-    active = jnp.asarray(rng.random(n) < 0.5)
-    total = jnp.asarray(rng.exponential(1e8, n).astype(np.float32) + 1e3)
-    done = jnp.asarray(rng.random(n).astype(np.float32)) * total
-    bw = jnp.asarray(rng.uniform(1e3, 1e7, m).astype(np.float32))
-    mode = jnp.asarray(rng.integers(0, 2, m), jnp.int32)
-    k = carousel_tick(link_id, active, done, total, bw, mode, float(dt),
-                      tick_impl="pallas_interpret")
-    r = carousel_tick(link_id, active, done, total, bw, mode, float(dt),
-                      tick_impl="jnp")
-    np.testing.assert_allclose(k[0], r[0], rtol=1e-4)
-    assert bool((k[1] == r[1]).all())
-
-
 @given(st.integers(2, 64), st.integers(1, 16))
 @settings(max_examples=20)
 def test_elastic_planner_divisibility(chips, tp_pow):

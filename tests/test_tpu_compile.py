@@ -172,8 +172,7 @@ def test_shard_program_compiles_over_four_v5e_without_collectives(
     # described chips instead.
     monkeypatch.setattr(sharding, "lane_mesh", lambda n: mesh)
     program = batched._shard_program.__wrapped__(
-        len(grid.site_names), grid.max_jobs_per_tick, grid.n_months, "jnp",
-        None, 4)
+        len(grid.site_names), grid.max_jobs_per_tick, grid.n_months, None, 4)
     args = _arg_shapes(grid, NamedSharding(mesh, PartitionSpec()),
                        NamedSharding(mesh, PartitionSpec(sharding.LANES_AXIS)))
     hlo = program.lower(*args).compile().as_text()

@@ -324,6 +324,71 @@ def _window_admit(live, size, used, limit, fifo, stale=None):
     return jnp.stack(cols, axis=1), extra
 
 
+def _apply_plans(st, plans, sizes, arrivals):
+    """Apply the tick's planned candidate windows to the ``[S, F]`` state
+    planes: one duplicate-safe scatter per plane. Returns the updated
+    planes by name.
+
+    Each plan is one window of per-candidate ``[S, C]`` vectors: the
+    candidate's ``site`` and ``fid``, whether its transfer starts
+    (``fire``) or it leaves the wait queue as ``stale``, its link id
+    ``m_vec``, a ``direct`` slot or a ``queued`` link ticket ``lq_val``,
+    and ``tstart``. ``arrivals``, the K-window's plan where the tick has
+    one, adds the wait-queue joins (``to_wait``, ``wq_val``) and the
+    consumer counters (``pend_add``, ``tail``, ``fin_val``); those
+    scatter over its rows alone. A file may appear in several rows: the
+    combinators (``add`` of deltas from the pre-scatter value, ``min``,
+    ``max``) give the same plane in any row order, and ``wq_wait``
+    takes its ``min`` before its ``max``.
+
+    The planes are addressed by ``(site, fid)`` as the scan carries
+    them, never flattened: on the TPU each reshape of a tiled plane to
+    ``[S·F]`` and back is a relayout copy of the whole plane, for
+    scatters that touch a few dozen rows. XLA:CPU expands each scatter
+    into a sequential per-row loop, so the K-window-only fields scatter
+    over a third of the rows.
+    """
+    def cat(key):
+        return jnp.concatenate([p[key].reshape(-1) for p in plans])
+
+    at = (cat("site"), cat("fid"))
+    fire = cat("fire")
+    queued = cat("queued")
+    cur_link = st["tr_link"][at]
+    cur_lqt = st["lq_ticket"][at]
+    out = dict(
+        disk_state=st["disk_state"].at[at].add(
+            jnp.where(fire, IN_FLIGHT - ABSENT, 0)),
+        # started/stale entries leave the wait queue (new waiters join
+        # below, after this min)
+        wq_wait=st["wq_wait"].at[at].min(~(fire | cat("stale"))),
+        tr_link=st["tr_link"].at[at].add(
+            jnp.where(fire, cat("m_vec") - cur_link, 0)),
+        tr_total=st["tr_total"].at[at].min(
+            jnp.where(fire, sizes[at], _INF)),
+        tr_slot=st["tr_slot"].at[at].max(cat("direct")),
+        tr_start=st["tr_start"].at[at].min(cat("tstart")),
+        lq_ticket=st["lq_ticket"].at[at].add(
+            jnp.where(queued, cat("lq_val") - cur_lqt, 0)),
+        lq_queued=st["lq_queued"].at[at].max(queued),
+    )
+    if arrivals is not None:
+        a = {key: v.reshape(-1) for key, v in arrivals.items()}
+        at1 = (a["site"], a["fid"])
+        cur_wqt = st["wq_ticket"][at1]
+        out["wq_wait"] = out["wq_wait"].at[at1].max(a["to_wait"])
+        out["wq_ticket"] = st["wq_ticket"].at[at1].add(
+            jnp.where(a["to_wait"], a["wq_val"] - cur_wqt, 0))
+        # incremental consumer counters (visible from the next tick on,
+        # matching the reference's deletions-before-submissions)
+        out["pend_cnt"] = st["pend_cnt"].at[at1].add(
+            jnp.where(a["pend_add"], 1, 0))
+        out["pend_tail"] = st["pend_tail"].at[at1].max(
+            jnp.where(a["pend_add"], a["tail"], 0.0))
+        out["fin_max"] = st["fin_max"].at[at1].max(a["fin_val"])
+    return out
+
+
 #: Per-site link-type order of the captured link-activity series (the
 #: ``3 * site + type`` link-id layout).
 LINK_TYPES = ("tape_to_disk", "gcs_to_disk", "disk_to_gcs")
@@ -387,7 +452,6 @@ def _lane_step_fns(S: int, K: int, n_months: int, record=None):
         now, dt, month, t, jobs_now = xs
         (sizes, pop, job_fid, job_submit_tick, job_tail, disk_limit,
          gcs_enabled, gcs_limit, min_pop, bw, slots, latency, mode) = const
-        F = sizes.shape[1]
         J = job_fid.shape[1]
         st = dict(state)
         site_rows = jnp.arange(S, dtype=jnp.int32)
@@ -556,8 +620,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, record=None):
                     jnp.sum(qd, axis=1).astype(jnp.int32))
                 occ3 = occ3.at[:, loc].add(
                     jnp.sum(d, axis=1).astype(jnp.float32))
-            rows = site_rows[:, None] * F + fids
-            return occ3, dict(rows=rows, fire=fire,
+            return occ3, dict(site=jnp.broadcast_to(site_rows[:, None],
+                                                   fids.shape),
+                              fid=fids, fire=fire,
                              m_vec=3 * site_rows[:, None] + link_local,
                              direct=direct, queued=queued, tstart=tstart,
                              lq_val=lq_val)
@@ -649,64 +714,9 @@ def _lane_step_fns(S: int, K: int, n_months: int, record=None):
                                          st["job_ready"])
 
         with jax.named_scope(TICK_SCOPES["apply"]):
-            # -- apply the planned windows: one scatter per state array.
-            # XLA:CPU expands each scatter into a sequential per-row loop, so
-            # rows are kept to the minimum: transfer/link plans scatter over
-            # both windows; the submission-only fields (wait-queue joins and
-            # the incremental consumer counters) exist only in the K-window
-            # and scatter over a third of the rows.
-            def cat(key):
-                return jnp.concatenate([p[key].reshape(-1) for p in plans])
-
-            rows = cat("rows")
-            fire = cat("fire")
-            stale = cat("stale")
-            m_vec = cat("m_vec")
-            direct = cat("direct")
-            queued = cat("queued")
-            tstart = cat("tstart")
-            lq_val = cat("lq_val")
-            size_c = sizes.reshape(-1)[rows]
-
-            def flat(name, update):
-                st[name] = update(st[name].reshape(-1)).reshape(S, F)
-
-            cur_link = st["tr_link"].reshape(-1)[rows]
-            cur_lqt = st["lq_ticket"].reshape(-1)[rows]
-            flat("disk_state", lambda a: a.at[rows].add(
-                jnp.where(fire, IN_FLIGHT - ABSENT, 0)))
-            # started/stale entries leave the wait queue (new waiters join in
-            # the K-window block below, preserving the min-before-max order)
-            flat("wq_wait", lambda a: a.at[rows].min(~(fire | stale)))
-            flat("tr_link", lambda a: a.at[rows].add(
-                jnp.where(fire, m_vec - cur_link, 0)))
-            flat("tr_total", lambda a: a.at[rows].min(
-                jnp.where(fire, size_c, _INF)))
-            flat("tr_slot", lambda a: a.at[rows].max(direct))
-            flat("tr_start", lambda a: a.at[rows].min(tstart))
-            flat("lq_ticket", lambda a: a.at[rows].add(
-                jnp.where(queued, lq_val - cur_lqt, 0)))
-            flat("lq_queued", lambda a: a.at[rows].max(queued))
-
-            if K > 0:  # K-window-only scatters (wait-queue joins + consumers)
-                g1 = plans[0]
-                rows1 = g1["rows"].reshape(-1)
-                to_wait = g1["to_wait"].reshape(-1)
-                wq_val = g1["wq_val"].reshape(-1)
-                pend_add = g1["pend_add"].reshape(-1)
-                fin_val = g1["fin_val"].reshape(-1)
-                tail_c = g1["tail"].reshape(-1)
-                cur_wqt = st["wq_ticket"].reshape(-1)[rows1]
-                flat("wq_wait", lambda a: a.at[rows1].max(to_wait))
-                flat("wq_ticket", lambda a: a.at[rows1].add(
-                    jnp.where(to_wait, wq_val - cur_wqt, 0)))
-                # incremental consumer counters (visible from the next tick
-                # on, matching the reference's deletions-before-submissions)
-                flat("pend_cnt", lambda a: a.at[rows1].add(
-                    jnp.where(pend_add, 1, 0)))
-                flat("pend_tail", lambda a: a.at[rows1].max(
-                    jnp.where(pend_add, tail_c, 0.0)))
-                flat("fin_max", lambda a: a.at[rows1].max(fin_val))
+            # -- apply the planned windows: one scatter per state array
+            st.update(_apply_plans(st, plans, sizes,
+                                   plans[0] if K > 0 else None))
 
             # -- integrate stored cloud volume (GB-seconds) per month ---------
             st["gbsec_mo"] = st["gbsec_mo"].at[month].add(

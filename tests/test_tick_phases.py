@@ -1,21 +1,33 @@
-"""Two phases of the batched tick against plain numpy oracles.
+"""Three phases of the batched tick against plain numpy oracles.
 
 ``repro.sim.batched._advance_transfers`` is the carousel's transfer
-advance over the ``[S, F]`` file planes, and ``_window_admit`` the
-prefix recurrence that admits a per-site candidate window (this tick's
-job arrivals, or the waiting queue's heads) against the disk limit.
+advance over the ``[S, F]`` file planes, ``_window_admit`` the prefix
+recurrence that admits a per-site candidate window (this tick's job
+arrivals, or the waiting queue's heads) against the disk limit, and
+``_apply_plans`` the scatter of both planned windows into the planes.
 Each is checked here on its own, from one site of 64 files up to three
-sites of 2049, and vmapped over lanes as the grid program runs it.
+sites of 2049, and vmapped over lanes as the grid program runs it. The
+whole program is held to a frozen copy of its outputs for one request
+of each benchmark deployment.
 """
 
 import itertools
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.sim.batched import _advance_transfers, _window_admit
+from repro.core.scenarios import ScenarioSpec, pack_specs
+from repro.sim.batched import (
+    ABSENT,
+    IN_FLIGHT,
+    _advance_transfers,
+    _apply_plans,
+    _window_admit,
+    simulate_packed,
+)
 
 MiB = 2.0 ** 20
 GiB = 2.0 ** 30
@@ -219,3 +231,180 @@ def test_tick_phases_vmap_per_lane(phase):
             else:
                 np.testing.assert_array_equal(np.asarray(got[li]),
                                               np.asarray(want))
+
+
+# ------------------------------------------------ applying the windows
+#: The planes ``_apply_plans`` writes: dtype and the fields of the
+#: arrivals' plan that reach them (``None``: both windows).
+APPLY_PLANES = {
+    "disk_state": np.int32, "wq_wait": bool, "tr_link": np.int32,
+    "tr_total": np.float32, "tr_slot": bool, "tr_start": np.float32,
+    "lq_ticket": np.int32, "lq_queued": bool, "wq_ticket": np.int32,
+    "pend_cnt": np.int32, "pend_tail": np.float32, "fin_max": np.float32,
+}
+ARRIVAL_PLANES = ("wq_ticket", "pend_cnt", "pend_tail", "fin_max")
+
+
+def _apply_inputs(S, F, C, W, seed):
+    """Random planes and two windows, the arrivals (``C`` per site) and
+    the queue heads (``W``), that name few files: every file of a site
+    appears several times, in both windows."""
+    rng = np.random.default_rng(seed)
+
+    def real(shape):  # bytes and seconds, and the planes' floats
+        return rng.uniform(1e3, 1e10, shape).astype(np.float32)
+
+    def plane(dtype):
+        if dtype is bool:
+            return rng.random((S, F)) < 0.5
+        if dtype is np.int32:
+            return rng.integers(-50, 50, (S, F)).astype(np.int32)
+        return real((S, F))
+
+    def window(n):
+        fid = rng.integers(0, F, (S, n)).astype(np.int32)
+        fire = rng.random((S, n)) < 0.6
+        direct = fire & (rng.random((S, n)) < 0.5)
+        return dict(
+            site=np.repeat(np.arange(S, dtype=np.int32)[:, None], n, 1),
+            fid=fid, fire=fire, stale=rng.random((S, n)) < 0.3,
+            m_vec=rng.integers(0, 3 * S, (S, n)).astype(np.int32),
+            direct=direct, queued=fire & ~direct,
+            tstart=np.where(direct, real((S, n)), np.inf),
+            lq_val=rng.integers(0, 1000, (S, n)).astype(np.int32))
+
+    st = {name: plane(dtype) for name, dtype in APPLY_PLANES.items()}
+    st["tr_total"][rng.random((S, F)) < 0.5] = np.inf  # no transfer yet
+    sizes = real((S, F))
+    arrivals, heads = window(C), window(W)
+    pend_add = rng.random((S, C)) < 0.5
+    arrivals.update(
+        to_wait=rng.random((S, C)) < 0.4,
+        wq_val=rng.integers(0, 1000, (S, C)).astype(np.int32),
+        pend_add=pend_add,
+        tail=real((S, C)),
+        fin_val=np.where(pend_add, -np.inf, real((S, C))))
+    return st, [arrivals, heads], sizes
+
+
+def _apply_oracle(st, plans, sizes, arrivals):
+    """The windows' rows one by one, in window order, in numpy: deltas
+    from the pre-scatter planes, ``wq_wait``'s ``min`` over every row
+    before its ``max`` over the arrivals'."""
+    out = {name: st[name].copy() for name in APPLY_PLANES
+           if arrivals is not None or name not in ARRIVAL_PLANES}
+
+    def rows(windows):
+        for p in windows:
+            for s, c in np.ndindex(p["fid"].shape):
+                yield p, (s, c), (p["site"][s, c], p["fid"][s, c])
+
+    for p, k, at in rows(plans):
+        if p["fire"][k]:
+            out["disk_state"][at] += IN_FLIGHT - ABSENT
+            out["tr_link"][at] += p["m_vec"][k] - st["tr_link"][at]
+            out["tr_total"][at] = min(out["tr_total"][at], sizes[at])
+        out["wq_wait"][at] &= ~(p["fire"][k] | p["stale"][k])
+        out["tr_slot"][at] |= p["direct"][k]
+        out["tr_start"][at] = min(out["tr_start"][at], p["tstart"][k])
+        if p["queued"][k]:
+            out["lq_ticket"][at] += p["lq_val"][k] - st["lq_ticket"][at]
+        out["lq_queued"][at] |= p["queued"][k]
+    if arrivals is not None:
+        for p, k, at in rows([arrivals]):
+            out["wq_wait"][at] |= p["to_wait"][k]
+            if p["to_wait"][k]:
+                out["wq_ticket"][at] += p["wq_val"][k] - st["wq_ticket"][at]
+            if p["pend_add"][k]:
+                out["pend_cnt"][at] += 1
+                out["pend_tail"][at] = max(out["pend_tail"][at],
+                                           p["tail"][k])
+            else:
+                out["pend_tail"][at] = max(out["pend_tail"][at], 0.0)
+            out["fin_max"][at] = max(out["fin_max"][at], p["fin_val"][k])
+    return out
+
+
+APPLY_CASES = {
+    # three files a site: every file is named by several rows of both
+    # windows
+    "crowded": (2, 3, 8, 4, 5),
+    "crowded_no_arrivals": (2, 3, 0, 4, 2),
+    "spread": (2, 1000, 8, 4, 3),
+    "three_sites": (3, 5, 16, 4, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(APPLY_CASES))
+def test_apply_plans_matches_row_by_row_oracle(case):
+    S, F, C, W, seed = APPLY_CASES[case]
+    st, plans, sizes = _apply_inputs(S, F, max(C, 1), W, seed)
+    if C == 0:
+        plans = plans[1:]
+    arrivals = plans[0] if C else None
+    got = jax.jit(_apply_plans)(st, plans, sizes, arrivals)
+    want = _apply_oracle(st, plans, sizes, arrivals)
+    assert sorted(got) == sorted(want)
+    for name, plane in want.items():
+        assert got[name].dtype == plane.dtype, name
+        np.testing.assert_array_equal(np.asarray(got[name]), plane,
+                                      err_msg=name)
+    if case == "crowded":
+        # the windows overlap, and the updates do change the planes
+        fids = [set(p["fid"][0]) for p in plans]
+        assert fids[0] & fids[1]
+        assert all((want[n] != st[n]).any() for n in want)
+
+
+def test_apply_plans_vmap_per_lane():
+    """Vmapped over lanes, as the grid program runs the tick, each lane
+    gives what a call on that lane alone gives."""
+    lanes = [_apply_inputs(2, 7, 8, 4, seed) for seed in range(3)]
+
+    def call(st, plans, sizes):
+        return _apply_plans(st, plans, sizes, plans[0])
+
+    stacked = jax.tree.map(lambda *x: np.stack(x), *lanes)
+    batched = jax.jit(jax.vmap(call))(*stacked)
+    for li, lane in enumerate(lanes):
+        single = jax.jit(call)(*lane)
+        for name in APPLY_PLANES:
+            np.testing.assert_array_equal(np.asarray(batched[name][li]),
+                                          np.asarray(single[name]),
+                                          err_msg=name)
+
+
+# -------------------------------------- the whole program, frozen outputs
+#: One request of each benchmark deployment (``benchmarks/chip/configs``)
+#: at 20,000 files per site: two lanes, 0.25 days at 60 s.
+FROZEN_CELLS = {
+    "cfgIII": dict(base="III", cache_tb=10.0, gcs_limit_tb=None),
+    "cfgII": dict(base="II", cache_tb=10.0, gcs_limit_tb=0.0),
+    "cfgIII-gcs20": dict(base="III", cache_tb=10.0, gcs_limit_tb=20.0),
+}
+FROZEN_SEEDS = (5, 2 ** 62 + 9)
+FROZEN_PATH = os.path.join(os.path.dirname(__file__), "testdata",
+                           "tick_f20k_outputs.npz")
+
+
+def _frozen_request(cell):
+    specs = [ScenarioSpec(days=0.25, n_files=20_000, seed=s,
+                          **FROZEN_CELLS[cell]) for s in FROZEN_SEEDS]
+    out = simulate_packed(pack_specs(specs, tick=60.0), record_series=True)
+    return {f"{cell}.{k}": np.asarray(v) for k, v in out.items()}
+
+
+@pytest.mark.parametrize("cell", sorted(FROZEN_CELLS))
+def test_program_outputs_equal_frozen_copy(cell):
+    """Every lane output of the request, the per-tick series included,
+    is bitwise the frozen copy written by the program before its
+    deferred scatters addressed the planes by ``(site, fid)``."""
+    got = _frozen_request(cell)
+    with np.load(FROZEN_PATH) as frozen:
+        want = {k: frozen[k] for k in frozen.files
+                if k.startswith(cell + ".")}
+    assert sorted(got) == sorted(want)
+    for key, value in want.items():
+        assert got[key].dtype == value.dtype, key
+        np.testing.assert_array_equal(got[key], value, err_msg=key)
+    assert (got[f"{cell}.ser_gcs"] > 0).any() == (cell != "cfgII")

@@ -146,6 +146,34 @@ def test_grid_program_selects_queue_heads_without_a_sort(
     assert re.search(r'op_name="[^"]*?/tick\.waitq[/"]', hlo)
 
 
+@pytest.mark.parametrize("target", ["cpu", "v5e"])
+def test_grid_program_applies_plans_to_unflattened_planes(
+        target, request, f20k_grid, no_compile_cache):
+    """``tick.apply`` scatters into the ``[S, F]`` planes as the scan
+    carries them: no instruction of the phase has a flattened plane's
+    shape, ``[L, S·F]`` or ``[L·S·F]`` (on the TPU each such reshape is
+    a relayout copy of the whole plane), and the phase still holds its
+    scatters."""
+    import jax
+
+    device = (request.getfixturevalue("topo").devices[0] if target == "v5e"
+              else jax.devices("cpu")[0])
+    hlo = _f20k_hlo(f20k_grid, device)
+    L, S, F = f20k_grid.sizes.shape
+    flat = {f"{L},{S * F}", f"{L * S * F}"}
+    apply_ops = [line for line in hlo.splitlines()
+                 if re.search(r'op_name="[^"]*?/tick\.apply[/"]', line)]
+    shapes = {m for line in apply_ops
+              for m in re.findall(r"= \w+\[([\d,]+)\]", line)}
+    assert not shapes & flat, [line.split(", metadata")[0]
+                               for line in apply_ops
+                               if any(f"[{f}]" in line for f in flat)]
+    scatters = [line for line in apply_ops if re.search(
+        r"= \S+ (scatter|fusion)\(.*op_name=\"[^\"]*?/tick\.apply/"
+        r"scatter-", line)]
+    assert scatters
+
+
 def test_v5e_grid_program_keeps_one_plane_wide_cumsum(topo, f20k_grid,
                                                       no_compile_cache):
     """On the TPU a cumsum lowers to ``reduce-window``s. The migrations'
